@@ -38,7 +38,10 @@ import time
 
 import jax
 
-jax.config.update("jax_enable_x64", True)
+# A client of a separate serve_cv process: stay off the accelerator (one
+# process owns a chip) and keep the server's float32 dtype policy, so the
+# bit-identical checks compare like with like.
+jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np

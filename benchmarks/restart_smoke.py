@@ -2,7 +2,7 @@
 
 Two phases against the same ``--state-dir``, bracketing a ``kill -9``:
 
-    # boot A: --plan-store pstore --compilation-cache xcache --save-plans
+    # boot A: JAX_COMPILATION_CACHE_DIR=xcache, --plan-store pstore --save-plans
     python benchmarks/restart_smoke.py --phase warm --url $URL --state-dir st
     kill -9 $SERVER_PID
     # boot B: same dirs + --warmup-from st/traffic.json
@@ -39,7 +39,10 @@ import time
 
 import jax
 
-jax.config.update("jax_enable_x64", True)
+# A client of a separate serve_cv process: stay off the accelerator (one
+# process owns a chip) and keep the server's float32 dtype policy, so the
+# bit-identical checks compare like with like.
+jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np

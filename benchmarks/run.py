@@ -111,8 +111,11 @@ def main() -> None:
         all_rows.extend(dict(section=name, git_sha=sha, timestamp=stamp, **r) for r in rows)
 
     if args.json:
+        devices = jax.devices()
         meta = {
             "backend": jax.default_backend(),
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
             "fast": bool(args.fast),
             "jax": jax.__version__,
             "git_sha": sha,

@@ -18,22 +18,38 @@ permutations, problems) is sharded.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import fastcv
-from repro.core.compat import shard_map
 from repro.core.folds import Folds
+from repro.kernels.common import dot_precision
 
 __all__ = [
     "distributed_gram",
     "distributed_hat_matrix",
     "distributed_permutation_binary",
     "sharded_null_from_plan",
+    "replicated",
     "sharded_problems",
     "searchlight_cv",
 ]
+
+
+def _ambient(mesh: Mesh):
+    """Make ``mesh`` the ambient mesh around a ``shard_map`` call.
+
+    Gathers inside the mapped bodies place their indices on the ambient
+    mesh. ``jax.set_mesh`` works only outside ``jit``; a caller that jits
+    these functions sets the mesh around its own call, and then it is
+    already ambient here.
+    """
+    if jax.sharding.get_abstract_mesh() == mesh.abstract_mesh:
+        return contextlib.nullcontext()
+    return jax.set_mesh(mesh)
 
 
 def distributed_gram(x: jax.Array, mesh: Mesh, *, center: bool = True,
@@ -46,14 +62,15 @@ def distributed_gram(x: jax.Array, mesh: Mesh, *, center: bool = True,
         x = x - jnp.mean(x, axis=0, keepdims=True)
 
     def local_gram(x_shard):
-        g = x_shard @ x_shard.T
+        g = jnp.matmul(x_shard, x_shard.T, precision=dot_precision(x_shard.dtype))
         return jax.lax.psum(g, feature_axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_gram, mesh=mesh,
         in_specs=P(None, feature_axis),
         out_specs=P(None, None))
-    return fn(x)
+    with _ambient(mesh):
+        return fn(x)
 
 
 def distributed_hat_matrix(x: jax.Array, lam: float, mesh: Mesh,
@@ -89,9 +106,9 @@ def distributed_permutation_binary(
     t_pad = -(-n_perm // n_shards) * n_shards
     perms = perm_lib.permutation_indices(key, y.shape[0], t_pad)  # (T, N)
 
-    null = sharded_null_from_plan(plan, y, perms, mesh, metric=metric,
-                                  perm_axes=perm_axes,
-                                  adjust_bias=adjust_bias)[:n_perm]
+    null = replicated(sharded_null_from_plan(plan, y, perms, mesh, metric=metric,
+                                             perm_axes=perm_axes,
+                                             adjust_bias=adjust_bias), mesh)[:n_perm]
     return perm_lib.PermutationResult(observed, null,
                                       perm_lib.p_value(observed, null))
 
@@ -103,22 +120,39 @@ def sharded_null_from_plan(plan: fastcv.CVPlan, y: jax.Array,
                            adjust_bias: bool = True) -> jax.Array:
     """Null-distribution metrics for ``perms`` (T, N), T sharded over
     ``perm_axes``; the plan (hat matrix + fold factors) is replicated.
+    The (T,) result stays sharded over ``perm_axes``; see :func:`replicated`
+    before slicing it.
 
     This is the serve engine's distributed permutation path: the plan is
     built once (possibly via :func:`distributed_gram`) and every batch of
     permutation requests fans out over the mesh's data-parallel axes.
+    The plan and ``y`` enter as replicated operands, not closures: a
+    closed-over array sharded on ``Explicit`` mesh axes (what
+    ``jax.make_mesh`` gives) is rejected by ``shard_map``, and the body's
+    gathers need the mesh set as the ambient one to place their indices.
     """
     from repro.core import permutation as perm_lib
 
-    def shard_fn(perm_shard):
+    def shard_fn(plan, y, perm_shard):
         yp = y[perm_shard].T                                   # (N, T_local)
         dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
         y_te = yp[plan.te_idx]
         return perm_lib._fold_metric_binary(dv, y_te, metric)  # (T_local,)
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=P(perm_axes),
-                   out_specs=P(perm_axes))
-    return fn(perms)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(), P(), P(perm_axes)),
+                       out_specs=P(perm_axes))
+    with _ambient(mesh):
+        return fn(plan, y, perms)
+
+
+def replicated(x: jax.Array, mesh: Mesh) -> jax.Array:
+    """``x`` on every device of ``mesh``, unsharded.
+
+    A sharded null must be gathered before a prefix is sliced off it: on
+    ``Explicit`` mesh axes a slice that cuts across shards has no
+    unambiguous output sharding and is rejected.
+    """
+    return jax.device_put(x, NamedSharding(mesh, P()))
 
 
 def _plan_from_h(h, folds: Folds, with_train_block: bool) -> fastcv.CVPlan:
@@ -131,10 +165,13 @@ def _plan_from_h(h, folds: Folds, with_train_block: bool) -> fastcv.CVPlan:
     return fastcv.CVPlan(h, folds.te_idx, folds.tr_idx, chol, h_tr_te)
 
 
-def sharded_problems(fn, xs: jax.Array, mesh: Mesh, *,
+def sharded_problems(fn, xs: jax.Array, mesh: Mesh, *shared,
                      problem_axes: tuple = ("pod", "data")) -> jax.Array:
-    """Map ``fn`` over the problem axis of ``xs`` (Q, ...), Q sharded over
-    the mesh's problem axes (those present in the mesh are used).
+    """Map ``fn(x, *shared)`` over the problem axis of ``xs`` (Q, ...), Q
+    sharded over the mesh's problem axes (those present in the mesh are
+    used). ``shared`` arrays (labels, fold indices) are replicated
+    operands; ``fn`` must not close over device arrays, which ``shard_map``
+    rejects when they are sharded on ``Explicit`` mesh axes.
 
     This is the generic problem-axis decomposition (paper §4.2:
     searchlights, time points, RSA sweeps): every problem is a fully
@@ -145,12 +182,14 @@ def sharded_problems(fn, xs: jax.Array, mesh: Mesh, *,
     """
     axes = tuple(a for a in problem_axes if a in mesh.axis_names)
 
-    def shard_fn(xs_shard):
-        return jax.vmap(fn)(xs_shard)
+    def shard_fn(xs_shard, *shared):
+        return jax.vmap(lambda x: fn(x, *shared))(xs_shard)
 
-    mapped = shard_map(shard_fn, mesh=mesh, in_specs=P(axes),
-                       out_specs=P(axes))
-    return mapped(xs)
+    in_specs = (P(axes),) + (P(),) * len(shared)
+    mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(axes))
+    with _ambient(mesh):
+        return mapped(xs, *shared)
 
 
 def searchlight_cv(xs: jax.Array, y: jax.Array, folds: Folds, lam: float,
@@ -162,12 +201,11 @@ def searchlight_cv(xs: jax.Array, y: jax.Array, folds: Folds, lam: float,
     Each problem runs the full analytical CV locally — zero cross-problem
     communication. Returns per-problem accuracy (Q,).
     """
-    te_idx, tr_idx = folds.te_idx, folds.tr_idx
-
-    def one_problem(x):
+    def one_problem(x, y, te_idx, tr_idx):
         dv, y_te = fastcv.binary_cv(x, y, Folds.with_indices(te_idx, tr_idx),
                                     lam=lam, adjust_bias=adjust_bias)
         pred = jnp.where(dv >= 0, 1.0, -1.0)
         return jnp.mean(pred == jnp.sign(y_te))
 
-    return sharded_problems(one_problem, xs, mesh, problem_axes=problem_axes)
+    return sharded_problems(one_problem, xs, mesh, y, folds.te_idx,
+                            folds.tr_idx, problem_axes=problem_axes)

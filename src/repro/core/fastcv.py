@@ -42,6 +42,7 @@ import numpy as np
 from jax.scipy.linalg import cho_factor, cho_solve
 
 from repro.core.folds import Folds
+from repro.kernels.common import dot_precision
 
 # reprolint: host-float64
 # (The incremental update lineage — update_plan/downdate_plan/
@@ -87,9 +88,10 @@ def hat_matrix_primal(x: jax.Array, lam: float = 0.0) -> jax.Array:
     p1 = xa.shape[1]
     # I₀: identity with the intercept entry zeroed (bias never penalised).
     i0 = jnp.eye(p1, dtype=x.dtype).at[p1 - 1, p1 - 1].set(0.0)
-    a = xa.T @ xa + jnp.asarray(lam, x.dtype) * i0
+    hi = dot_precision(x.dtype)
+    a = jnp.matmul(xa.T, xa, precision=hi) + jnp.asarray(lam, x.dtype) * i0
     c = cho_factor(a)
-    return xa @ cho_solve(c, xa.T)
+    return jnp.matmul(xa, cho_solve(c, xa.T), precision=hi)
 
 
 def hat_matrix_dual(x: jax.Array, lam: float, gram: Optional[jax.Array] = None) -> jax.Array:
@@ -103,7 +105,7 @@ def hat_matrix_dual(x: jax.Array, lam: float, gram: Optional[jax.Array] = None) 
     n = x.shape[0]
     if gram is None:
         xc = x - jnp.mean(x, axis=0, keepdims=True)
-        gram = xc @ xc.T
+        gram = jnp.matmul(xc, xc.T, precision=dot_precision(x.dtype))
     lam = jnp.asarray(lam, x.dtype)
     c = cho_factor(gram + lam * jnp.eye(n, dtype=x.dtype))
     # G (G+λI)⁻¹ is symmetric (G and (G+λI)⁻¹ share an eigenbasis).
@@ -262,7 +264,8 @@ def cv_errors(plan: CVPlan, y: jax.Array, *, fused: bool = False):
     squeeze = y.ndim == 1
     if squeeze:
         y = y[:, None]
-    y_hat = plan.h @ y                          # (N, B)
+    hi = dot_precision(plan.h.dtype)
+    y_hat = jnp.matmul(plan.h, y, precision=hi)  # (N, B)
     e_hat = y - y_hat
     e_te = e_hat[plan.te_idx]                   # (K, m, B)
     t = jax.vmap(_chol_solve_lower)(plan.chol_ih, e_te)   # (I−H_Te)⁻¹ ê_Te
@@ -270,7 +273,7 @@ def cv_errors(plan: CVPlan, y: jax.Array, *, fused: bool = False):
     y_dot_tr = None
     if plan.h_tr_te is not None:
         e_tr = e_hat[plan.tr_idx]               # (K, N-m, B)
-        e_dot_tr = e_tr + jnp.einsum("knm,kmb->knb", plan.h_tr_te, t)
+        e_dot_tr = e_tr + jnp.einsum("knm,kmb->knb", plan.h_tr_te, t, precision=hi)
         y_dot_tr = y[plan.tr_idx] - e_dot_tr
     if squeeze:
         y_dot_te = y_dot_te[..., 0]
@@ -305,10 +308,12 @@ def cv_errors_fused(plan: CVPlan, y: jax.Array):
         y_dot_te = y_te - t
         y_dot_tr = None
     else:
-        e_hat = y - plan.h @ y
+        hi = dot_precision(plan.h.dtype)
+        e_hat = y - jnp.matmul(plan.h, y, precision=hi)
         t = foldsolve(h_te, e_hat[te])
         y_dot_te = y_te - t
-        e_dot_tr = e_hat[plan.tr_idx] + jnp.einsum("knm,kmb->knb", plan.h_tr_te, t)
+        e_dot_tr = e_hat[plan.tr_idx] + jnp.einsum(
+            "knm,kmb->knb", plan.h_tr_te, t, precision=hi)
         y_dot_tr = y[plan.tr_idx] - e_dot_tr
     if squeeze:
         y_dot_te = y_dot_te[..., 0]

@@ -32,6 +32,7 @@ from jax.scipy.linalg import cho_factor, cho_solve, solve_triangular
 
 from repro.core import fastcv
 from repro.core.folds import Folds
+from repro.kernels.common import dot_precision
 
 __all__ = [
     "onehot",
@@ -48,7 +49,7 @@ __all__ = [
 _EPS = 1e-10
 
 
-def onehot(y: jax.Array, num_classes: int, dtype=jnp.float64) -> jax.Array:
+def onehot(y: jax.Array, num_classes: int, dtype=float) -> jax.Array:
     return jax.nn.one_hot(y, num_classes, dtype=dtype)
 
 
@@ -177,11 +178,12 @@ def _fold_predict(y_dot_te, y_dot_tr, y1h_tr, dtype):
     """
     n_tr = y1h_tr.shape[0]
     counts = jnp.sum(y1h_tr, axis=0)
-    m_mat = y_dot_tr.T @ y1h_tr / n_tr                   # Ẏ_Trᵀ Y_Tr / N_Tr
+    dot = partial(jnp.matmul, precision=dot_precision(dtype))
+    m_mat = dot(y_dot_tr.T, y1h_tr) / n_tr               # Ẏ_Trᵀ Y_Tr / N_Tr
     theta_d, _ = _os_step2(m_mat, counts / n_tr, n_tr)
-    scores_te = y_dot_te @ theta_d                       # (m, C-1)
-    scores_tr = y_dot_tr @ theta_d                       # (N-m, C-1)
-    centroids = (y1h_tr.T @ scores_tr) / jnp.maximum(counts, 1.0)[:, None]
+    scores_te = dot(y_dot_te, theta_d)                   # (m, C-1)
+    scores_tr = dot(y_dot_tr, theta_d)                   # (N-m, C-1)
+    centroids = dot(y1h_tr.T, scores_tr) / jnp.maximum(counts, 1.0)[:, None]
     d2 = jnp.sum((scores_te[:, None, :] - centroids[None]) ** 2, axis=-1)
     return jnp.argmin(d2, axis=-1)
 

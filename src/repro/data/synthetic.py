@@ -24,7 +24,7 @@ def _wishart_cholesky(key: jax.Array, p: int, dof: int, dtype) -> jax.Array:
 
 
 def make_classification(key: jax.Array, n: int, p: int, num_classes: int = 2,
-                        dtype=jnp.float64, class_sep: float = 1.0):
+                        dtype=float, class_sep: float = 1.0):
     """Paper §2.12 generator. Returns (x (N,P), y int (N,) in [0, C)).
 
     Equal class proportions; centroids uniform on the unit hypersphere
@@ -41,7 +41,7 @@ def make_classification(key: jax.Array, n: int, p: int, num_classes: int = 2,
 
 
 def make_regression(key: jax.Array, n: int, p: int, noise: float = 0.1,
-                    dtype=jnp.float64):
+                    dtype=float):
     """Linear model y = Xw* + b* + ε for regression CV tests/benchmarks."""
     k_x, k_w, k_e = jax.random.split(key, 3)
     x = jax.random.normal(k_x, (n, p), dtype)

@@ -5,6 +5,10 @@ block shapes). On this CPU-only container they are validated with
 ``interpret=True`` which executes the kernel bodies in Python; the
 ``interpret`` default below auto-detects the platform so the same call
 sites run compiled on real TPUs.
+
+The chip path is float32: the TPU compiler has no float64, so the wrappers
+refuse a float64 operand on a compiled (non-interpret) call with a
+:class:`TypeError` instead of Mosaic's bare ``NotImplementedError``.
 """
 
 from __future__ import annotations
@@ -12,7 +16,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["default_interpret", "default_fused", "pad_to", "cdiv"]
+__all__ = [
+    "default_interpret",
+    "default_fused",
+    "check_device_dtypes",
+    "dot_precision",
+    "pad_to",
+    "cdiv",
+]
 
 
 def default_interpret() -> bool:
@@ -27,6 +38,32 @@ def default_fused() -> bool:
     path stays reachable everywhere by passing ``fused=True`` explicitly.
     """
     return jax.default_backend() == "tpu"
+
+
+def check_device_dtypes(kernel: str, interpret: bool, *arrays: jax.Array) -> None:
+    """Raise TypeError if a compiled kernel call would receive float64."""
+    if interpret:
+        return
+    for a in arrays:
+        if a.dtype == jnp.float64:
+            raise TypeError(
+                f"{kernel}: float64 operand of shape {a.shape} on the compiled TPU "
+                "path; the TPU has no float64. Build plans and labels in float32 "
+                "(leave jax_enable_x64 off, or cast with .astype(jnp.float32))."
+            )
+
+
+def dot_precision(dtype):
+    """Contraction precision for operands of ``dtype``.
+
+    float32 operands get ``HIGHEST``: the TPU's default for a float32
+    matmul is a single bfloat16 pass, which is not the float32 the
+    ``precision="fp32"`` build promises. Narrower operands (the
+    ``bf16_gram`` mode) keep the default single pass. A no-op on CPU.
+    """
+    if jnp.dtype(dtype).itemsize >= 4:
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 def cdiv(a: int, b: int) -> int:

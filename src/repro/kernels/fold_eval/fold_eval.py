@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot_precision
 from repro.kernels.foldsolve.foldsolve import gauss_jordan_solve
 
 DEFAULT_BLOCK_N = 256
@@ -45,6 +46,7 @@ def _fold_eval_kernel(h_rows_ref, h_te_ref, y_ref, y_te_ref,
     # the hat_apply contraction, restricted to this fold's te rows:
     # acc += H[te_k, chunk] @ Y[chunk]   →   (H·y)_Te after the last chunk
     acc_ref[...] += jnp.dot(h_rows_ref[0], y_ref[...],
+                            precision=dot_precision(y_ref.dtype),
                             preferred_element_type=acc_ref.dtype)
 
     @pl.when(c == n_chunks - 1)

@@ -20,7 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret, pad_to
+from repro.kernels.common import check_device_dtypes, default_interpret, pad_to
 from repro.kernels.fold_eval.fold_eval import (
     DEFAULT_BLOCK_B,
     DEFAULT_BLOCK_N,
@@ -61,6 +61,7 @@ def fold_eval(h_rows: jax.Array, h_te: jax.Array, y: jax.Array,
     """
     if interpret is None:
         interpret = default_interpret()
+    check_device_dtypes("fold_eval", interpret, h_rows, h_te, y, y_te)
     k, m, n = h_rows.shape
     b = y.shape[1]
     bn = _block(block_n, DEFAULT_BLOCK_N, n)

@@ -21,7 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
+from repro.kernels.common import check_device_dtypes, default_interpret, dot_precision
 from repro.kernels.foldsolve.foldsolve import foldsolve_pallas
 
 __all__ = ["foldsolve", "fold_jitter", "fold_residual_bad"]
@@ -49,7 +49,7 @@ def fold_residual_bad(h_te: jax.Array, t: jax.Array, e: jax.Array) -> jax.Array:
     m = h_te.shape[-1]
     eye = jnp.eye(m, dtype=h_te.dtype)
     a = eye[None] - h_te
-    r = jnp.einsum("kij,kjb->kib", a, t) - e
+    r = jnp.einsum("kij,kjb->kib", a, t, precision=dot_precision(t.dtype)) - e
     scale = 1.0 + jnp.max(jnp.abs(e), axis=(1, 2))
     finite = jnp.all(jnp.isfinite(t), axis=(1, 2))
     # NaN propagates through max as NaN; comparisons with NaN are False,
@@ -72,6 +72,7 @@ def foldsolve(h_te: jax.Array, e_te: jax.Array, *,
     """
     if interpret is None:
         interpret = default_interpret()
+    check_device_dtypes("foldsolve", interpret, h_te, e_te)
     squeeze = e_te.ndim == 2
     e = e_te[..., None] if squeeze else e_te
     out = foldsolve_pallas(h_te, e, interpret=interpret)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot_precision
+
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_P = 512
 
@@ -35,6 +37,7 @@ def _gram_kernel(x_i_ref, x_j_ref, out_ref, acc_ref, *, n_chunks: int):
     acc_ref[...] += jax.lax.dot_general(
         x_i_ref[...], x_j_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=dot_precision(x_i_ref.dtype),
         preferred_element_type=acc_ref.dtype,
     )
 

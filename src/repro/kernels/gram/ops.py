@@ -23,15 +23,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret, pad_to
+from repro.kernels.common import check_device_dtypes, default_interpret, dot_precision, pad_to
 from repro.kernels.gram.gram import gram_pallas
 
 __all__ = ["gram", "centered_gram", "centered_gram_xla", "check_precision",
            "PRECISIONS"]
 
-#: Gram/hat build precisions: "fp32" = the working dtype end-to-end (the
-#: historical behaviour; the name predates x64 test configs), "bf16_gram" =
-#: bf16 inputs + f32 accumulation for the Gram product only.
+#: Gram/hat build precisions: "fp32" = full-precision contractions in the
+#: working dtype (float32 on the chip, float64 in the x64 test suite),
+#: "bf16_gram" = bf16 inputs + f32 accumulation for the Gram product only.
 PRECISIONS = ("fp32", "bf16_gram")
 
 
@@ -60,6 +60,7 @@ def gram(x: jax.Array, *, center: bool = False, block_n: Optional[int] = None,
     """
     if interpret is None:
         interpret = default_interpret()
+    check_device_dtypes("gram", interpret, x)
     precision = check_precision(precision)
     if center:
         x = x - jnp.mean(x, axis=0, keepdims=True)
@@ -92,7 +93,7 @@ def centered_gram_xla(x: jax.Array, *,
     precision = check_precision(precision)
     xc = x - jnp.mean(x, axis=0, keepdims=True)
     if precision == "fp32":
-        return xc @ xc.T
+        return jnp.matmul(xc, xc.T, precision=dot_precision(xc.dtype))
     xb = xc.astype(jnp.bfloat16)
     g = jnp.matmul(xb, xb.T, preferred_element_type=jnp.float32)
     return g.astype(x.dtype)

@@ -8,7 +8,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret, pad_to
+from repro.kernels.common import check_device_dtypes, default_interpret, pad_to
 from repro.kernels.pairdist.pairdist import NORM_LANES, pairdist_pallas
 
 __all__ = ["pairwise_sq_dists"]
@@ -27,6 +27,7 @@ def pairwise_sq_dists(u: jax.Array, *, block_c: Optional[int] = None,
     """
     if interpret is None:
         interpret = default_interpret()
+    check_device_dtypes("pairdist", interpret, u)
     c, p = u.shape
     bc = min(block_c or 128, max(8, 1 << (c - 1).bit_length()))
     bp = min(block_p or 512, max(8, 1 << (p - 1).bit_length()))

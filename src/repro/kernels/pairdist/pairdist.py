@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot_precision
+
 DEFAULT_BLOCK_C = 128
 DEFAULT_BLOCK_P = 512
 
@@ -40,6 +42,7 @@ def _pairdist_kernel(u_i_ref, u_j_ref, n_i_ref, n_j_ref, out_ref, acc_ref,
     acc_ref[...] += jax.lax.dot_general(
         u_i_ref[...], u_j_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=dot_precision(u_i_ref.dtype),
         preferred_element_type=acc_ref.dtype,
     )
 

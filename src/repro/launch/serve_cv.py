@@ -33,11 +33,13 @@ requests/s and the engine's cache / compile statistics.
 
 ``--plan-store DIR`` adds the durable plan tier: cache misses read
 verified plans from DIR before rebuilding, and with ``--save-plans``
-every fresh build is persisted (write-behind) for the next boot.
-``--compilation-cache DIR`` turns on jax's persistent XLA compilation
-cache. Together they make the full warm-boot sequence::
+every fresh build is persisted (write-behind) for the next boot. jax's
+persistent compilation cache is always on: in ``JAX_COMPILATION_CACHE_DIR``
+when that is set, otherwise in ``<checkout>/.jax_cache``
+(:mod:`repro.launch.compile_cache`). Together they make the full
+warm-boot sequence::
 
-    serve_cv --http 0 --plan-store X --compilation-cache Y \\
+    JAX_COMPILATION_CACHE_DIR=Y serve_cv --http 0 --plan-store X \\
              --warmup-from traffic.json --save-plans
 
 reach 0-plan-build, ~0-compile-time steady state in seconds (CI's
@@ -50,6 +52,10 @@ batched results at ``POST /v1/workloads``, SSE progress streams at
 ``POST /v1/workloads/stream``, wire-side dataset registration at ``POST
 /v1/datasets`` — until interrupted. ``--record-traffic`` composes: the
 (task, bucket) set observed *over the wire* is dumped on shutdown.
+
+Everything on the device is float32 (x64 stays off; the TPU has no
+float64). Only the rank-k update lineage of ``kind="update"`` computes in
+host float64 and casts back to the plan's dtype.
 """
 
 from __future__ import annotations
@@ -59,15 +65,13 @@ import asyncio
 import time
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 import numpy as np
 
 from repro import rsa
 from repro.core import folds as foldlib
 from repro.data import eeg, synthetic
+from repro.launch.compile_cache import setup_compilation_cache
 from repro.serve import Client, CVEngine, EngineConfig, TrafficLog, Workload
 
 
@@ -82,11 +86,12 @@ def build_workloads(args, client):
         key = jax.random.PRNGKey(args.seed + d)
         if args.data == "eeg":
             ds = eeg.simulate_subject(key, n_trials=args.n,
-                                      num_classes=num_classes, dtype=jnp.float64)
+                                      num_classes=num_classes, dtype=jnp.float32)
             x, y_int = eeg.windowed_features(ds, 200.0), ds.y
         else:
             x, y_int = synthetic.make_classification(
-                key, args.n, args.p, num_classes=num_classes, class_sep=2.0)
+                key, args.n, args.p, num_classes=num_classes, class_sep=2.0,
+                dtype=jnp.float32)
         n = int(x.shape[0])
         handle = client.register(x, foldlib.kfold(n, args.k, seed=d), args.lam)
         y_bin = jnp.where(y_int % 2 == 0, -1.0, 1.0)
@@ -128,7 +133,7 @@ def build_rsa_workloads(args, client):
     for d in range(args.datasets):
         key = jax.random.PRNGKey(args.seed + d)
         x, y_cond = synthetic.make_classification(
-            key, args.n, args.p, num_classes=c, class_sep=2.0)
+            key, args.n, args.p, num_classes=c, class_sep=2.0, dtype=jnp.float32)
         handle = client.register(
             x, foldlib.stratified_kfold(y_cond, args.k, seed=d), args.lam)
         mu = rsa.condition_means(x, y_cond, c)
@@ -291,56 +296,25 @@ def run_window(client, args, datasets):
               "geometry was not preserved")
 
 
-def setup_compilation_cache(path):
-    """Point jax's persistent compilation cache at ``path`` (opt-in).
-
-    Thresholds drop to zero so even this workload's small CPU programs
-    persist — the restart-smoke job needs every program cached, not just
-    the slow ones. Failures degrade to a warning: the persistent cache
-    is a warm-boot accelerator, never a serving prerequisite.
-    """
-    if not path:
-        return False
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as cc
-
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        cc.set_cache_dir(path)
-        print(f"[serve_cv] XLA compilation cache -> {path}")
-        return True
-    except Exception as e:  # noqa: BLE001 - best-effort accelerator
-        print(f"[serve_cv] warning: compilation cache unavailable: {e}")
-        return False
-
-
 def start_profile(profile_dir):
-    """Begin a jax.profiler capture; returns True when it actually started.
+    """Begin a jax.profiler capture when ``--profile-dir`` asked for one.
 
-    Failures (unsupported backend, missing tensorboard plugin, already
-    active) degrade to a warning — profiling is an extra, never a
-    prerequisite for serving.
+    Returns whether a capture started. A capture that was asked for and
+    cannot start raises: a run that silently measures nothing is worse
+    than one that stops.
     """
     if not profile_dir:
         return False
-    try:
-        jax.profiler.start_trace(profile_dir)
-        print(f"[serve_cv] profiling -> {profile_dir}")
-        return True
-    except Exception as e:  # noqa: BLE001 - best-effort tooling
-        print(f"[serve_cv] warning: profiler failed to start: {e}")
-        return False
+    jax.profiler.start_trace(profile_dir)
+    print(f"[serve_cv] profiling -> {profile_dir}")
+    return True
 
 
 def stop_profile(started):
     if not started:
         return
-    try:
-        jax.profiler.stop_trace()
-        print("[serve_cv] profile capture complete")
-    except Exception as e:  # noqa: BLE001 - best-effort tooling
-        print(f"[serve_cv] warning: profiler failed to stop: {e}")
+    jax.profiler.stop_trace()
+    print("[serve_cv] profile capture complete")
 
 
 def print_stage_summary(engine):
@@ -448,10 +422,6 @@ def main():
     ap.add_argument("--save-plans", action="store_true",
                     help="with --plan-store: persist every freshly built "
                     "plan (write-behind) for the next boot")
-    ap.add_argument("--compilation-cache", metavar="DIR", default=None,
-                    help="persistent XLA compilation cache directory "
-                    "(jax.experimental.compilation_cache); repeat boots "
-                    "skip compile time for already-seen programs")
     ap.add_argument("--store-mb", type=int, default=4096,
                     help="plan-store byte budget in MiB (GC evicts oldest "
                     "entries over it; default 4096)")
@@ -478,7 +448,7 @@ def main():
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
         print("[serve_cv] jax_debug_nans on: evals re-run de-optimized on NaN")
-    setup_compilation_cache(args.compilation_cache)
+    print(f"[serve_cv] XLA compilation cache -> {setup_compilation_cache()}")
 
     engine = CVEngine(EngineConfig(
         cache_bytes=args.cache_mb << 20,

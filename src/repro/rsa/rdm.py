@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core import fastcv, metrics, multiclass
 from repro.core.folds import Folds
+from repro.kernels.common import dot_precision
 
 __all__ = [
     "RDMCache",
@@ -127,7 +128,7 @@ def condition_pairs(num_classes: int) -> np.ndarray:
     return np.stack([a, b], axis=1).astype(np.int32)
 
 
-def pair_contrast_columns(y_cond: jax.Array, num_classes: int, dtype=jnp.float64) -> jax.Array:
+def pair_contrast_columns(y_cond: jax.Array, num_classes: int, dtype=float) -> jax.Array:
     """(N, B) matrix of ±1/0 pairwise contrast columns.
 
     Column j encodes pair (a, b) = ``condition_pairs(C)[j]``: +1 on
@@ -230,9 +231,7 @@ def rdm_from_confusion(preds: jax.Array, y_te: jax.Array, num_classes: int) -> j
     0 on the diagonal. Conditions the classifier confuses often are
     representationally close.
     """
-    conf = metrics.confusion_matrix(preds.reshape(-1), y_te.reshape(-1), num_classes).astype(
-        jnp.float64
-    )
+    conf = metrics.confusion_matrix(preds.reshape(-1), y_te.reshape(-1), num_classes).astype(float)
     rates = conf / jnp.maximum(jnp.sum(conf, axis=1, keepdims=True), 1.0)
     sim = 0.5 * (rates + rates.T)
     eye = jnp.eye(num_classes, dtype=bool)
@@ -254,10 +253,10 @@ def condition_means(x: jax.Array, y_cond: jax.Array, num_classes: int) -> jax.Ar
     """(C, P) mean feature pattern per condition."""
     oh = jax.nn.one_hot(y_cond, num_classes, dtype=x.dtype)  # (N, C)
     counts = jnp.maximum(jnp.sum(oh, axis=0), 1.0)
-    return (oh.T @ x) / counts[:, None]
+    return jnp.matmul(oh.T, x, precision=dot_precision(x.dtype)) / counts[:, None]
 
 
-def ring_rdm(num_classes: int, dtype=jnp.float64) -> jax.Array:
+def ring_rdm(num_classes: int, dtype=float) -> jax.Array:
     """(C, C) circular-distance model RDM: d(a, b) = min(|a−b|, C−|a−b|).
 
     The standard "ring" candidate structure for ordered condition sets
@@ -314,9 +313,7 @@ def searchlight_rdm(
     """
     from repro.core.distributed import sharded_problems
 
-    te_idx, tr_idx = folds.te_idx, folds.tr_idx
-
-    def one_problem(x):
+    def one_problem(x, y_cond, te_idx, tr_idx):
         return rdm_binary(
             x,
             y_cond,
@@ -328,7 +325,9 @@ def searchlight_rdm(
             mode=mode,
         )
 
-    return sharded_problems(one_problem, xs, mesh, problem_axes=problem_axes)
+    return sharded_problems(
+        one_problem, xs, mesh, y_cond, folds.te_idx, folds.tr_idx, problem_axes=problem_axes
+    )
 
 
 # ---------------------------------------------------------------------------

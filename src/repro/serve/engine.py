@@ -1109,7 +1109,7 @@ class CVEngine:
                 plan = self._strip_train(plan)
             y = y.astype(plan.h.dtype)
             if self.config.mesh is not None:
-                from repro.core.distributed import sharded_null_from_plan
+                from repro.core.distributed import replicated, sharded_null_from_plan
 
                 n_shards = 1
                 for a in self.config.perm_axes:
@@ -1125,7 +1125,9 @@ class CVEngine:
                     metric=metric,
                     perm_axes=self.config.perm_axes,
                     adjust_bias=adjust_bias,
-                )[:b]
+                )
+                # callers slice prefixes (n_perm < bucket): gather first
+                out = replicated(out, self.config.mesh)[:b]
             else:
                 fn = self._perm_binary_fn(metric, adjust_bias)
                 out = fn(plan, y, self._pad_rows(perms, owned=True)[0])[:b]

@@ -15,7 +15,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 jax.config.update("jax_enable_x64", True)
 
@@ -42,6 +42,11 @@ def main():
     xc = x - jnp.mean(x, axis=0, keepdims=True)
     check("distributed_gram",
           np.allclose(np.asarray(g_dist), np.asarray(xc @ xc.T), atol=1e-8))
+    # a caller's jit under its own set_mesh: the mesh is already ambient
+    with jax.set_mesh(mesh):
+        g_jit = jax.jit(lambda v: D.distributed_gram(v, mesh))(xs)
+    check("distributed_gram_under_jit",
+          np.array_equal(np.asarray(g_jit), np.asarray(g_dist)))
 
     # ---- distributed hat matrix == single-device hat matrix --------------
     h_dist = D.distributed_hat_matrix(xs, 1.0, mesh)
@@ -74,6 +79,10 @@ def main():
     check("searchlight_finite", bool(np.isfinite(np.asarray(acc)).all()))
 
     # ---- sharded train step runs and matches unsharded loss ---------------
+    # The LM substrate shards through sharding constraints, not shard_map:
+    # it runs on a mesh of Auto axes (jax.make_mesh now defaults to Explicit).
+    auto = (AxisType.Auto, AxisType.Auto)
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
     from repro.configs.base import get_config
     from repro.launch import sharding as sh
     from repro.optim import optimizer as O
@@ -110,7 +119,7 @@ def main():
 
     with tempfile.TemporaryDirectory() as td:
         ckpt.save(td, 1, {"params": new_p})
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
         p_sh2 = sh.param_sharding_tree(params, mesh2)
         restored, _ = ckpt.restore(td, 1, {"params": params},
                                    {"params": p_sh2})

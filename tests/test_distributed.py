@@ -27,7 +27,7 @@ def test_distributed_suite():
     out = proc.stdout + proc.stderr
     assert proc.returncode == 0, out[-4000:]
     assert "ALL_OK" in proc.stdout, out[-4000:]
-    for name in ("distributed_gram", "distributed_hat",
+    for name in ("distributed_gram", "distributed_gram_under_jit", "distributed_hat",
                  "distributed_permutation_null", "searchlight_shape",
                  "sharded_train_loss_matches", "elastic_restore_values",
                  "elastic_restore_mesh"):

@@ -350,3 +350,22 @@ def test_gram_fp32_precision_is_default():
         np.asarray(gram(x, center=True, precision="fp32", interpret=True)))
     with pytest.raises(ValueError, match="precision"):
         gram(x, precision="fp8")
+
+
+# ------------------------------------------------------ device dtypes ----
+
+_F64_CALLS = {
+    "gram": lambda z: gram(z((16, 32)), interpret=False),
+    "pairdist": lambda z: pairwise_sq_dists(z((4, 32)), interpret=False),
+    "foldsolve": lambda z: foldsolve(z((2, 4, 4)), z((2, 4, 3)), interpret=False),
+    "fold_eval": lambda z: fold_eval(z((2, 4, 8)), z((2, 4, 4)), z((8, 3)), z((2, 4, 3)),
+                                     interpret=False),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_F64_CALLS))
+def test_compiled_kernel_rejects_float64(kernel):
+    """The TPU has no float64: a compiled call names the problem instead of
+    failing deep inside the kernel compiler."""
+    with pytest.raises(TypeError, match=f"{kernel}: float64 operand"):
+        _F64_CALLS[kernel](lambda shape: jnp.zeros(shape, jnp.float64))
