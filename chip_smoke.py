@@ -8,22 +8,27 @@ One chip. Simulates one subject at the source paper's §2.13 shape with
 ``repro.data.eeg`` from a fixed seed — 787 trials, 380 channels, 200 ms
 windowed features (P = 1900), float32 — once with C = 2 and once with
 C = 3 classes, K = 10 folds, λ = 1. Both datasets register through
-``Client``; the HTTP edge starts in this process (``EdgeThread``) and six
-workloads go over loopback (``HTTPClient``) and through the in-process
-``Client``: binary LDA CV with the default bias adjustment (the
-``foldsolve`` route), binary CV without it (the fused ``fold_eval``
-route), ridge CV, 3-class CV, a 1000-draw permutation test and an RSA
-workload whose model RDM is built by the ``pairdist`` kernel. Every
-answer must be a result; wire and in-process answers must be equal; and
-they must agree with a plain NumPy float64 refit per fold (the paper's
-"standard approach"):
+``Client``; the HTTP edge starts in this process (``EdgeThread``) and
+seven workloads of four kinds go over loopback (``HTTPClient``) and
+through the in-process ``Client``: ``cv`` as binary LDA with the default
+bias adjustment (the ``foldsolve`` route), binary without it (the fused
+``fold_eval`` route), ridge and 3-class LDA; a 1000-draw ``permutation``
+test; an ``rsa`` workload (pairwise-contrast RDM, scored against model
+RDMs the client builds, one of them with the ``pairdist`` kernel); and
+a ``tune`` workload over a λ grid. Every answer must be a
+result; wire and in-process answers must be equal; and they must agree
+with a plain NumPy float64 refit per fold (the paper's "standard
+approach"):
 
-* decision values within 1e-3 · max|ref|, predicted labels equal wherever
-  |ref| exceeds that;
+* decision values, RSA pair contrasts and the client's pairdist RDM
+  within 1e-3 · max|ref|, predicted labels equal wherever |ref| exceeds
+  that;
 * 3-class predictions equal except at ties of the reference's nearest
   centroids (distance gap within 1e-3 of the largest distance);
 * the first 32 permutation-null accuracies equal the reference on the same
-  index draws within one test trial.
+  index draws within one test trial;
+* every LOO score of the λ grid within 1e-3 of the reference's (the closed
+  form of exact leave-one-out refits), and the same best λ.
 
 Four chips. Builds a (data=2, model=2) mesh and a ``gram_impl="distributed"``
 engine on it next to a one-device engine, runs only the feature-sharded
@@ -67,6 +72,7 @@ LAM = 1.0
 N_PERM = 1000
 N_PERM_CHECKED = 32  # null draws compared with the float64 reference
 REL_TOL = 1e-3  # decision values: |dev − ref| <= REL_TOL · max|ref|
+RSA_PAIRS = ((0, 1), (0, 2), (1, 2))  # contrast column order, repro.rsa.condition_pairs(3)
 SEED = 0
 
 
@@ -132,13 +138,14 @@ def lda_dvals(fit_te, fit_tr, y, tr, adjust_bias):
     """Binary LDA decision values from the ridge fits of ±1 labels (K, m, B).
 
     With ``adjust_bias`` the regression bias becomes the LDA bias: minus
-    the midpoint of the two classes' mean training decision values.
+    the midpoint of the two classes' mean training decision values. A 0
+    label (a sample outside an RSA pair contrast) is in neither class.
     """
     if not adjust_bias:
         return fit_te
     y_tr = y[tr]
     pos = (y_tr > 0).astype(np.float64)
-    neg = 1.0 - pos
+    neg = (y_tr < 0).astype(np.float64)
     mu1 = (fit_tr * pos).sum(1) / pos.sum(1)
     mu2 = (fit_tr * neg).sum(1) / neg.sum(1)
     return fit_te - 0.5 * (mu1 + mu2)[:, None, :]
@@ -163,6 +170,28 @@ def multiclass_dists(fit_te, fit_tr, y1h, tr):
         centroids = (yk.T @ s_tr) / counts[:, None]
         out.append(((s_te[:, None, :] - centroids[None]) ** 2).sum(-1))
     return np.stack(out)
+
+
+def pair_contrasts(dv, cols_te):
+    """RSA "contrast" dissimilarity per pair column (B,): mean decision
+    value on the pair's +1 test samples minus that on its −1 samples."""
+    pos, neg = (cols_te > 0), (cols_te < 0)
+    return (dv * pos).sum((0, 1)) / pos.sum((0, 1)) - (dv * neg).sum((0, 1)) / neg.sum((0, 1))
+
+
+def loo_scores(x, y, lambdas):
+    """Mean squared leave-one-out residual of ridge with an unpenalised
+    intercept per λ, float64: ė_i = ê_i / (1 − H_ii), the closed form of
+    N refits, with H = 11ᵀ/N + G_c (G_c + λI)⁻¹ built by direct solves."""
+    xc = x - x.mean(0)
+    g = xc @ xc.T
+    n = len(y)
+    out = []
+    for lam in lambdas:
+        h = 1.0 / n + np.linalg.solve(g + lam * np.eye(n), g)
+        e = y - h @ y
+        out.append(np.mean((e / (1.0 - np.diag(h))) ** 2))
+    return np.array(out)
 
 
 def fold_accuracy(dv, y_te):
@@ -192,7 +221,7 @@ def check_values(name, got, ref):
 
 
 def one_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) -> dict:
-    """Serve the six workload kinds over HTTP and in process; check them.
+    """Serve the seven workloads over HTTP and in process; check them.
 
     Returns a summary of the checks; raises on the first failure.
     """
@@ -206,6 +235,10 @@ def one_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) -
     y2 = np.where(y2_int == 0, -1.0, 1.0).astype(np.float32)
     w_true = rng.standard_normal(x2.shape[1]) / np.sqrt(x2.shape[1])
     y_ridge = (x2_64 @ w_true + 0.5 * rng.standard_normal(n)).astype(np.float32)
+    # tune over λ = tr(G_c)/N · 10^[-2, 2]: below that the LOO denominators
+    # 1 − H_ii cancel to a few float32 ulps, whatever the matmul precision
+    scale = np.square(x2_64 - x2_64.mean(0)).sum() / n
+    lambdas = (scale * np.logspace(-2, 2, 9)).astype(np.float32)
     models = jnp.stack([
         rsa.euclidean_rdm(rsa.condition_means(x3, jnp.asarray(y3), 3)),  # pairdist
         rsa.ring_rdm(3, jnp.float32),
@@ -216,7 +249,7 @@ def one_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) -
     h2 = client.register(x2, folds2, LAM)
     h3 = client.register(x3, folds3, LAM)
     kinds = ["cv/binary", "cv/binary-no-bias", "cv/ridge", "cv/multiclass",
-             "permutation", "rsa"]
+             "permutation", "rsa", "tune"]
     workloads = [
         Workload(kind="cv", dataset=h2, y=y2),
         Workload(kind="cv", dataset=h2, y=y2, adjust_bias=False),
@@ -225,7 +258,8 @@ def one_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) -
                  num_classes=3),
         Workload(kind="permutation", dataset=h2, y=y2, n_perm=n_perm, seed=seed + 11),
         Workload(kind="rsa", dataset=h3, y=y3.astype(np.int32), num_classes=3,
-                 model_rdms=models, n_perm=64, seed=seed + 12),
+                 dissimilarity="contrast", model_rdms=models, n_perm=64, seed=seed + 12),
+        Workload(kind="tune", x=x2, y=y_ridge, lambdas=lambdas),
     ]
 
     with EdgeThread(engine) as edge, HTTPClient(edge.url) as wire:
@@ -303,13 +337,33 @@ def one_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) -
             "permutation accuracies differ by more than one trial")
 
     rdm_resp = in_process[5]
+    pair_cols = np.stack([y1h[:, a] - y1h[:, b] for a, b in RSA_PAIRS], axis=1)
+    pr_te, pr_tr = ridge_refit(x3_64, pair_cols, te3, tr3, LAM)
+    pair_ref = pair_contrasts(lda_dvals(pr_te, pr_tr, pair_cols, tr3, True), pair_cols[te3])
+    rdm_ref = np.zeros((3, 3))
+    for (a, b), v in zip(RSA_PAIRS, pair_ref):
+        rdm_ref[a, b] = rdm_ref[b, a] = v
+    devs["rsa"] = check_values("rsa empirical RDM (pair contrasts)", rdm_resp.rdm, rdm_ref)
+    require(bool(np.isfinite(np.asarray(rdm_resp.model_scores)).all()),
+            "rsa: non-finite model scores")
+    log(f"  rsa model scores {np.asarray(rdm_resp.model_scores)}")
     mu = np.stack([x3_64[y3 == c].mean(0) for c in range(3)])
     pd_ref = ((mu[:, None, :] - mu[None]) ** 2).sum(-1)
-    devs["pairdist"] = check_values("rsa model RDM (pairdist)", models[0], pd_ref)
-    require(bool(np.isfinite(np.asarray(rdm_resp.rdm)).all())
-            and bool(np.isfinite(np.asarray(rdm_resp.model_scores)).all()),
-            "rsa: non-finite RDM or model scores")
-    log(f"  rsa: empirical RDM finite, model scores {np.asarray(rdm_resp.model_scores)}")
+    devs["pairdist"] = check_values("client model RDM (pairdist kernel)", models[0], pd_ref)
+
+    tune = in_process[6].result
+    lambdas = np.asarray(tune.lambdas, np.float64)
+    loo_ref = loo_scores(x2_64, y_ridge.astype(np.float64), lambdas)
+    loo_got = np.asarray(tune.scores, np.float64)
+    tune_dev = float((np.abs(loo_got - loo_ref) / loo_ref).max())
+    best = int(np.argmin(loo_got))
+    best_ref = int(np.argmin(loo_ref))
+    log(f"  tune: {lambdas.size} λ, max per-λ |dev−ref|/ref = {tune_dev:.3e} (bar {REL_TOL:g}); "
+        f"best λ index {best} (reference {best_ref})")
+    require(tune_dev <= REL_TOL, f"tune: LOO scores deviate by {tune_dev:.3e} of the reference")
+    require(best == best_ref or loo_ref[best] <= (1 + REL_TOL) * loo_ref[best_ref],
+            f"tune: best λ index {best} != reference {best_ref}")
+    devs["tune"] = tune_dev
 
     stats = jax.devices()[0].memory_stats() or {}
     log(f"device peak_bytes_in_use: {stats.get('peak_bytes_in_use', 'not reported')}")

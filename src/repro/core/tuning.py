@@ -16,10 +16,13 @@ repro.core.shrinkage and convertible with Eq. 18.)
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.common import dot_precision
 
 __all__ = ["RidgeTuneResult", "loo_curve", "tune_ridge"]
 
@@ -31,9 +34,13 @@ class RidgeTuneResult(NamedTuple):
     scores: jax.Array           # (L,) criterion per λ (lower is better)
 
 
+def _centered(x: jax.Array) -> jax.Array:
+    return x - jnp.mean(x, axis=0, keepdims=True)
+
+
 def _eig_gram(x: jax.Array):
-    xc = x - jnp.mean(x, axis=0, keepdims=True)
-    g = xc @ xc.T
+    xc = _centered(x)
+    g = jnp.matmul(xc, xc.T, precision=dot_precision(x.dtype))
     evals, u = jnp.linalg.eigh(g)
     return jnp.maximum(evals, 0.0), u
 
@@ -49,13 +56,13 @@ def loo_curve(x: jax.Array, y: jax.Array, lambdas: jax.Array,
     n = x.shape[0]
     y = y.astype(jnp.float64 if x.dtype == jnp.float64 else jnp.float32)
     evals, u = _eig_gram(x)
-    uy = u.T @ y                                   # (N,)
-    ones_coef = u.T @ jnp.ones((n,), y.dtype)      # for H 11ᵀ/N part
+    dot = partial(jnp.matmul, precision=dot_precision(y.dtype))
+    uy = dot(u.T, y)                               # (N,)
 
     def one_lambda(lam):
         w = evals / (evals + lam)                  # (N,) spectral filter
         # ŷ = H y = 1/N Σy + U diag(w) Uᵀ y
-        y_hat = jnp.mean(y) + u @ (w * uy)
+        y_hat = jnp.mean(y) + dot(u, w * uy)
         # H_ii = 1/N + Σ_k w_k U_ik²  ... plus cross term from 11ᵀ/N and
         # U diag(w) Uᵀ? The two parts are NOT orthogonal in general, but
         # H = 1/N·11ᵀ + U W Uᵀ exactly (DESIGN §2), so
@@ -77,8 +84,7 @@ def tune_ridge(x: jax.Array, y: jax.Array, lambdas=None,
 
     Serving equivalent: ``Workload(kind="tune", x=x, y=y, ...)``."""
     if lambdas is None:
-        xc = x - jnp.mean(x, axis=0, keepdims=True)
-        scale = jnp.trace(xc @ xc.T) / x.shape[0]
+        scale = jnp.sum(jnp.square(_centered(x))) / x.shape[0]  # tr(G_c) / N
         lambdas = scale * jnp.logspace(-4, 2, 25)
     lambdas = jnp.asarray(lambdas)
     scores = loo_curve(x, y, lambdas, criterion=criterion)

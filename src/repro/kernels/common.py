@@ -60,6 +60,9 @@ def dot_precision(dtype):
     matmul is a single bfloat16 pass, which is not the float32 the
     ``precision="fp32"`` build promises. Narrower operands (the
     ``bf16_gram`` mode) keep the default single pass. A no-op on CPU.
+    Every served contraction takes its precision from here;
+    ``tests/test_precision.py`` walks the served families' jaxprs to keep
+    it that way.
     """
     if jnp.dtype(dtype).itemsize >= 4:
         return jax.lax.Precision.HIGHEST

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot_precision
+
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_B = 128
 
@@ -36,6 +38,7 @@ def _hat_apply_kernel(h_ref, y_k_ref, y_i_ref, err_ref, acc_ref, *, n_chunks: in
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(h_ref[...], y_k_ref[...],
+                            precision=dot_precision(h_ref.dtype),
                             preferred_element_type=acc_ref.dtype)
 
     @pl.when(k == n_chunks - 1)

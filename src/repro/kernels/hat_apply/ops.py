@@ -7,7 +7,7 @@ from typing import Optional
 
 import jax
 
-from repro.kernels.common import default_interpret, pad_to
+from repro.kernels.common import check_device_dtypes, default_interpret, pad_to
 from repro.kernels.hat_apply.hat_apply import hat_apply_pallas
 
 __all__ = ["hat_errors"]
@@ -23,6 +23,7 @@ def hat_errors(h: jax.Array, y: jax.Array, *, block_n: Optional[int] = None,
     """
     if interpret is None:
         interpret = default_interpret()
+    check_device_dtypes("hat_apply", interpret, h, y)
     squeeze = y.ndim == 1
     yb = y[:, None] if squeeze else y
     n, b = yb.shape

@@ -15,7 +15,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 jax.config.update("jax_enable_x64", True)
 
@@ -79,10 +79,10 @@ def main():
     check("searchlight_finite", bool(np.isfinite(np.asarray(acc)).all()))
 
     # ---- sharded train step runs and matches unsharded loss ---------------
-    # The LM substrate shards through sharding constraints, not shard_map:
-    # it runs on a mesh of Auto axes (jax.make_mesh now defaults to Explicit).
-    auto = (AxisType.Auto, AxisType.Auto)
-    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
+    # on the meshes the LM launchers build (Auto axes, repro.launch.mesh)
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(model_axis=4)
     from repro.configs.base import get_config
     from repro.launch import sharding as sh
     from repro.optim import optimizer as O
@@ -119,7 +119,7 @@ def main():
 
     with tempfile.TemporaryDirectory() as td:
         ckpt.save(td, 1, {"params": new_p})
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
+        mesh2 = make_host_mesh(model_axis=2)
         p_sh2 = sh.param_sharding_tree(params, mesh2)
         restored, _ = ckpt.restore(td, 1, {"params": params},
                                    {"params": p_sh2})
