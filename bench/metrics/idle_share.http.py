@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device (%)."""
+
+
+def read(rec):
+    dt = rec.get("device_trace")
+    if not dt or dt["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - dt["busy_s"] / dt["window_s"])
