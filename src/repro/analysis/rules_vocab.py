@@ -35,7 +35,7 @@ class StageVocabulary(Rule):
             name = None
             f = node.func
             if isinstance(f, ast.Attribute):
-                if f.attr == "span" and node.args:
+                if f.attr in ("span", "open_span", "annotate") and node.args:
                     name = _literal_str(node.args[0])
                 elif f.attr == "add" and len(node.args) == 2:
                     # Trace.add(stage, seconds) — two positional args keeps

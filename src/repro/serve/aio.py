@@ -58,6 +58,14 @@ _STOP = object()
 _STREAM_END = object()
 
 
+def _after_wait(t_submit: float, traces, job):
+    """Executor side of :meth:`AsyncEngineServer._run` for traced jobs."""
+    wait = time.perf_counter() - t_submit
+    for trace in traces:
+        trace.add("executor_wait", wait)
+    return job()
+
+
 class AsyncEngineServer:
     """Asyncio server: gather-window micro-batching + streaming workloads.
 
@@ -141,16 +149,24 @@ class AsyncEngineServer:
         if self._worker_task is None or self._stopping:
             raise RuntimeError("server is not running")
 
-    def _run(self, fn, *args, **kw):
+    def _run(self, fn, *args, traces=None, **kw):
         """Run one engine call on the executor thread; await the result.
 
         Guarded so a stream outliving :meth:`stop` fails fast instead of
         silently falling back to the loop's default (multi-thread)
         executor — which would break the single-engine-thread invariant.
+
+        ``traces`` are the traces of the requests the job serves: the
+        executor thread adds the job's wait, from submission to its start,
+        to each as ``executor_wait`` — under the trace's open span (the
+        edge's ``decode``), else at top level.
         """
         if self._executor is None:
             raise RuntimeError("server is not running")
-        return self._loop.run_in_executor(self._executor, functools.partial(fn, *args, **kw))
+        job = functools.partial(fn, *args, **kw)
+        if traces:
+            job = functools.partial(_after_wait, time.perf_counter(), traces, job)
+        return self._loop.run_in_executor(self._executor, job)
 
     # -- client side -------------------------------------------------------
 
@@ -160,15 +176,23 @@ class AsyncEngineServer:
         # Trace from the submit side so gather-window queue time is a
         # measured batch_wait stage; the trace rides the workload object
         # onto the engine thread (run_in_executor does not copy context).
+        # A trace created here is finished here; one handed in (the HTTP
+        # edge's) is finished by whoever created it.
         tracer = self.engine.tracer
+        created = None
         if tracer.enabled and trace_of(request) is None:
-            attach_trace(request, tracer.trace())
+            created = tracer.trace()
+            attach_trace(request, created)
         trace = trace_of(request)
         if trace is not None:
             trace.mark_enqueue()
         fut = self._loop.create_future()
         await self._queue.put((request, fut))
-        return await fut
+        try:
+            return await fut
+        finally:
+            if created is not None:
+                tracer.finish(created)
 
     async def register(self, x, folds, lam: float, mode: str = "auto"):
         """Register a dataset on the engine thread; returns its handle.
@@ -255,16 +279,21 @@ class AsyncEngineServer:
         # One dequeue timestamp for the whole gather window: each member's
         # submit->here latency becomes its batch_wait stage.
         now = time.perf_counter()
+        traces = [] if self.engine.tracer.enabled else None
         for req in requests:
             trace = trace_of(req)
             if trace is not None:
                 trace.note_dequeue(now)
+                if traces is not None:
+                    traces.append(trace)
         self.engine.metrics.observe("gather_window_occupancy", len(batch))
         try:
             # Per-entry result-or-error: a malformed workload (or an
             # unknown/evicted dataset handle) fails only its own future,
             # never sibling submitters sharing the gather window.
-            responses = await self._run(run_workloads, self.engine, requests, return_errors=True)
+            responses = await self._run(
+                run_workloads, self.engine, requests, return_errors=True, traces=traces
+            )
         except Exception as e:  # noqa: BLE001 - fanned out to submitters
             for fut in futures:
                 if not fut.done():

@@ -164,14 +164,17 @@ class EngineServer:
             # measured stage (batch_wait) instead of silently inflating
             # eval time. The trace rides the workload object across the
             # thread boundary (context vars do not).
+            # A trace made here is finished when the worker sets the
+            # future; one handed in is finished by its maker.
             tracer = self.engine.tracer
+            fut: Future = Future()
             if tracer.enabled and trace_of(request) is None:
                 trace = tracer.trace()
                 attach_trace(request, trace)
+                fut.add_done_callback(lambda _f, t=trace: tracer.finish(t))
             trace = trace_of(request)
             if trace is not None:
                 trace.mark_enqueue()
-            fut: Future = Future()
             self._queue.put((request, fut))
             return fut
 

@@ -292,8 +292,10 @@ class CVEngine:
         Every subsequent workload gets a span tree (decode → encode),
         attached to its response as ``timings`` and kept in a bounded ring
         of ``ring`` traces (``GET /v1/trace``, :meth:`Tracer.summary`).
-        Tracing adds per-stage clock reads and a ``block_until_ready``
-        per span — leave it off for peak-throughput serving.
+        Tracing adds per-stage clock reads, a ``block_until_ready`` and a
+        ``repro.<stage>`` profiler annotation per span, and a ``gc``
+        callback that times collector pauses — leave it off for
+        peak-throughput serving.
         """
         self.tracer.enable(ring=ring)
 

@@ -505,7 +505,7 @@ class HTTPEdge:
         if self.record is not None:
             self.record.record(w, self.engine.config.buckets, stream_chunk=stream_chunk)
 
-    def _offload(self, fn, *args):
+    def _offload(self, fn, *args, traces=None):
         """Run work on the engine's executor thread.
 
         Two invariants ride on this: engine state is only ever touched
@@ -513,8 +513,26 @@ class HTTPEdge:
         and the event loop never blocks on multi-MB JSON codecs or
         ``jnp.asarray`` device puts — so concurrent SSE streams and
         health checks stay live while a big request is (de)serialised.
+        ``traces`` get the job's wait for that thread as ``executor_wait``.
         """
-        return self.server._run(fn, *args)
+        return self.server._run(fn, *args, traces=traces)
+
+    async def _decode_traced(self, trace, decode, body: bytes):
+        """``decode(body)`` on the executor thread, for a request whose
+        ``trace`` started when its body had been read. The ``decode`` span
+        covers the wait for the executor thread (an ``executor_wait``
+        child) and the work, which alone carries the ``repro.decode``
+        profiler annotation."""
+        tracer = self.engine.tracer
+
+        def work():
+            with tracer.annotate("decode"):
+                return decode(body)
+
+        span = trace.open_span("decode")
+        decoded = await self._offload(work, traces=(trace,))
+        trace.close_span(span)
+        return decoded
 
     # -- connection handling ----------------------------------------------
 
@@ -705,17 +723,20 @@ class HTTPEdge:
 
     async def _serve_batch(self, body: bytes) -> bytes:
         tracer = self.engine.tracer
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        results, live = await self._offload(self._decode_batch, body)
-        if tracer.enabled:
-            # Wire decode is shared by the whole batch; attribute the full
-            # duration to each member (exact for the single-workload case,
-            # which is how latency budgets are measured).
-            dt_decode = time.perf_counter() - t0
-            for _i, w in live:
-                tr = tracer.trace()
-                tr.add("decode", dt_decode)
-                attach_trace(w, tr)
+        trace = tracer.trace()
+        traces = None
+        if trace is None:
+            results, live = await self._offload(self._decode_batch, body)
+        else:
+            results, live = await self._decode_traced(trace, self._decode_batch, body)
+            # One trace per workload, from arrival to the encoded response,
+            # finished here: members of one body share its decode and its
+            # wire encode, each attributed in full (exact for the
+            # single-workload case, which is how latency budgets are measured).
+            if live:
+                traces = [trace] + [trace.fork() for _ in live[1:]]
+                for (_i, w), tr in zip(live, traces):
+                    attach_trace(w, tr)
         self.http_errors += sum(r is not None for r in results)
         for _i, w in live:
             self._note(w)
@@ -725,24 +746,27 @@ class HTTPEdge:
         self.http_errors += sum(isinstance(o, BaseException) for o in outs)
 
         def encode() -> bytes:
-            # Traces are already finished by run_workloads, so the wire
-            # encode goes straight into the stage histogram rather than a
-            # span (the "encode" span inside the trace covers response
-            # construction; this covers JSON serialisation).
-            t_enc = time.perf_counter() if tracer.enabled else 0.0
             for (i, _), out in zip(live, outs):
                 if isinstance(out, BaseException):
                     results[i] = _error_entry(out, phase="serve")
                 else:
                     results[i] = {"ok": True, "response": response_to_dict(out)}
-            encoded = json.dumps({"results": results}).encode("utf-8")
-            if tracer.enabled:
-                self.engine.metrics.observe(
-                    "stage_latency_seconds", time.perf_counter() - t_enc, stage="encode"
-                )
+            return json.dumps({"results": results}).encode("utf-8")
+
+        if traces is None:
+            return await self._offload(encode)
+
+        def encode_traced() -> bytes:
+            t0 = time.perf_counter()
+            with tracer.annotate("encode"):
+                encoded = encode()
+            dt = time.perf_counter() - t0
+            for tr in traces:
+                tr.add("encode", dt)
+                tracer.finish(tr)
             return encoded
 
-        return await self._offload(encode)
+        return await self._offload(encode_traced, traces=traces)
 
     @staticmethod
     def _decode_register(body: bytes) -> DatasetSpec:
@@ -801,13 +825,12 @@ class HTTPEdge:
     async def _serve_stream(self, body: bytes, writer) -> bool:
         # Decode + validate *before* committing to SSE, so malformed input
         # gets a structured JSON error via the generic handler.
-        tracer = self.engine.tracer
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        w = await self._offload(self._decode_workload, body)
-        if tracer.enabled:
-            tr = tracer.trace()
-            tr.add("decode", time.perf_counter() - t0)
-            attach_trace(w, tr)
+        trace = self.engine.tracer.trace()
+        if trace is None:
+            w = await self._offload(self._decode_workload, body)
+        else:
+            w = await self._decode_traced(trace, self._decode_workload, body)
+            attach_trace(w, trace)
         self._note(w, stream_chunk=self.server.stream_chunk)
         self.http_streams += 1
         head = (
@@ -840,6 +863,9 @@ class HTTPEdge:
             _write_chunk(writer, f"event: error\ndata: {err}\n\n".encode("utf-8"))
         finally:
             await gen.aclose()
+            # A streamed kind's trace was finished with its final event; a
+            # batched kind's trace is finished here.
+            self.engine.tracer.finish(trace)
         _write_chunk(writer, b"")  # terminal chunk: the stream is complete
         await writer.drain()
         return True

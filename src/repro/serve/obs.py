@@ -100,7 +100,7 @@ METRICS = {
         "kind": "histogram",
         "labels": ("stage",),
         "buckets": "latency",
-        "help": "Per-stage request latency (traced requests only)",
+        "help": "Per-stage request latency (traced requests only), and GC pauses while tracing",
     },
     "gather_window_occupancy": {
         "kind": "histogram",

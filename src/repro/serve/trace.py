@@ -28,22 +28,44 @@ engine. Workload objects are frozen dataclasses, so attachment uses
 finished (bench loops re-send the same objects) gets a *fresh* trace —
 finished traces are never reused.
 
+The profiler clock
+------------------
+While tracing is enabled, every live span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<stage>`` on the thread
+doing the work, so a ``jax.profiler`` capture shows the engine's stages
+beside the device's operations. Work timed once and attributed after the
+fact (:meth:`Trace.add`: a coalesced eval, the edge's codecs) is wrapped
+in :meth:`Tracer.annotate` instead. An annotation never stays open across
+an ``await``: TraceMe nesting is per thread, and coroutines interleave on
+the loop thread; a span held open there uses :meth:`Trace.open_span`,
+which opens none. An enabled tracer also hooks ``gc.callbacks``: each
+interpreter garbage-collection pause is a ``repro.gc`` annotation and a
+``stage_latency_seconds{stage="gc"}`` observation, never part of a
+request's ``timings``.
+
 Cost model: when tracing is disabled (the default), every hook degenerates
 to a shared null context manager / ``None`` checks — no clock reads, no
-allocation, and crucially no extra ``block_until_ready`` (``Tracer.sync``
-is a no-op without an active trace), so jax's async dispatch pipeline is
-untouched. The ISSUE's overhead guard (disabled ⇒ zero extra compiles,
-``timings`` absent) is enforced by ``tests/test_obs.py``.
+allocation, no profiler annotation, no ``gc`` hook, and crucially no extra
+``block_until_ready`` (``Tracer.sync`` is a no-op without an active
+trace), so jax's async dispatch pipeline is untouched. The overhead guard
+(disabled ⇒ zero extra compiles, ``timings`` absent, nothing annotated or
+hooked) is enforced by ``tests/test_obs.py`` and
+``tests/test_obs_profiler.py``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
+import gc
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Optional
+
+import jax
 
 # reprolint: monotonic-time
 # (Span intervals and batch deadlines must survive wall-clock jumps —
@@ -70,9 +92,11 @@ STAGES = (
     "cache_lookup",  # plan_key fingerprint + cache probe
     "store_load",  # disk plan-store read + integrity check (miss path)
     "batch_wait",  # submit -> dequeue latency (thread/async servers)
+    "executor_wait",  # submit -> start of a job on the async server's engine thread
     "eval",  # bucketed jitted eval (scores, RDMs, tune sweeps)
     "null_chunk",  # permutation-null chunks (monolithic or streamed)
     "encode",  # response assembly (+ wire JSON on the HTTP edge)
+    "gc",  # interpreter garbage-collection pause, any thread; not a request stage
 )
 
 _CURRENT: "contextvars.ContextVar[Optional[Trace]]" = contextvars.ContextVar(
@@ -158,17 +182,41 @@ class Trace:
     # -- span construction -------------------------------------------------
 
     def span(self, name: str) -> "_SpanCtx":
-        """Context manager timing one stage; nests under any open span."""
+        """Context manager timing one stage; nests under any open span,
+        and opens the ``repro.<name>`` profiler annotation around it."""
         return _SpanCtx(self, name)
 
+    def open_span(self, name: str) -> Span:
+        """Start a stage that nests under any open span, with no profiler
+        annotation: for a span held open across an ``await``. Close it
+        with :meth:`close_span`; spans added meanwhile become its children."""
+        span = Span(name, time.perf_counter() - self._t0)
+        self._sink().append(span)
+        self._stack.append(span)
+        return span
+
+    def close_span(self, span: Span) -> None:
+        span.duration = time.perf_counter() - self._t0 - span.start
+        if self._stack and self._stack[-1] is span:
+            self._stack.pop()
+
     def add(self, name: str, seconds: float) -> Span:
-        """Append an already-measured stage (e.g. a shared coalesced eval
-        timed once for the whole flush group, attributed to each member)."""
+        """Append an already-measured stage that ends now (e.g. a shared
+        coalesced eval timed once for the whole flush group, attributed to
+        each member); it nests under any open span."""
         now = time.perf_counter() - self._t0
         span = Span(name, max(0.0, now - seconds))
         span.duration = seconds
         self._sink().append(span)
         return span
+
+    def fork(self) -> "Trace":
+        """A new trace with this one's start and its spans so far, for the
+        members of one HTTP body: they share its arrival and decode."""
+        twin = Trace(self.kind, self.estimator)
+        twin._t0 = self._t0
+        twin.spans = list(self.spans)
+        return twin
 
     def mark_enqueue(self) -> None:
         """Submit side of the batch_wait stage (thread/async servers)."""
@@ -215,23 +263,21 @@ class Trace:
 
 
 class _SpanCtx:
-    __slots__ = ("trace", "name", "_span", "_start")
+    __slots__ = ("trace", "name", "_span", "_annotation")
 
     def __init__(self, trace: Trace, name: str):
         self.trace = trace
         self.name = name
 
     def __enter__(self) -> Span:
-        self._start = time.perf_counter()
-        self._span = Span(self.name, self._start - self.trace._t0)
-        self.trace._sink().append(self._span)
-        self.trace._stack.append(self._span)
+        self._span = self.trace.open_span(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(f"repro.{self.name}")
+        self._annotation.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> None:
-        self._span.duration = time.perf_counter() - self._start
-        if self.trace._stack and self.trace._stack[-1] is self._span:
-            self.trace._stack.pop()
+        self._annotation.__exit__(*exc)
+        self.trace.close_span(self._span)
 
 
 _NULL_CM = contextlib.nullcontext()
@@ -267,9 +313,13 @@ class Tracer:
 
     def __init__(self, registry=None, ring: int = 256, enabled: bool = False):
         self.registry = registry
-        self.enabled = enabled
+        self.enabled = False
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(1, int(ring)))
+        self._gc_unhook: Optional[weakref.finalize] = None
+        self._gc_pause: Optional[tuple] = None  # (annotation, start) of the open pause
+        if enabled:
+            self.enable()
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -277,10 +327,19 @@ class Tracer:
         if ring is not None and ring != self._ring.maxlen:
             with self._lock:
                 self._ring = deque(self._ring, maxlen=max(1, int(ring)))
+        if self._gc_unhook is None:
+            # The hook holds the tracer weakly, and is unhooked when the
+            # tracer dies, so an engine never left enabled outlives its use.
+            hook = functools.partial(_on_gc, weakref.ref(self))
+            gc.callbacks.append(hook)
+            self._gc_unhook = weakref.finalize(self, _unhook, hook)
         self.enabled = True
 
     def disable(self) -> None:
         self.enabled = False
+        if self._gc_unhook is not None:
+            self._gc_unhook()
+            self._gc_unhook = None
 
     @property
     def ring_size(self) -> int:
@@ -306,6 +365,12 @@ class Tracer:
         trace = _CURRENT.get()
         return trace.span(name) if trace is not None else _NULL_CM
 
+    def annotate(self, stage: str):
+        """The ``repro.<stage>`` profiler annotation alone, for work timed
+        outside a span and attributed with :meth:`Trace.add`; a null CM
+        when disabled."""
+        return jax.profiler.TraceAnnotation(f"repro.{stage}") if self.enabled else _NULL_CM
+
     def sync(self, value):
         """``jax.block_until_ready`` **only when a trace is active** — span
         durations must measure compute, not async-dispatch enqueue time;
@@ -328,6 +393,22 @@ class Tracer:
         if self.registry is not None and "stage_latency_seconds" in self.registry:
             for stage, seconds in trace.timings().items():
                 self.registry.observe("stage_latency_seconds", seconds, stage=stage)
+
+    def _on_gc_phase(self, phase: str) -> None:
+        # Runs inside the collector, on the thread that triggered it; the
+        # collector is not re-entrant, so one pause is open at a time.
+        if phase == "start":
+            annotation = jax.profiler.TraceAnnotation("repro.gc")
+            annotation.__enter__()
+            self._gc_pause = (annotation, time.perf_counter())
+        elif self._gc_pause is not None:
+            annotation, start = self._gc_pause
+            self._gc_pause = None
+            annotation.__exit__(None, None, None)
+            if self.registry is not None and "stage_latency_seconds" in self.registry:
+                self.registry.observe(
+                    "stage_latency_seconds", time.perf_counter() - start, stage="gc"
+                )
 
     def last(self, n: int = 32) -> list:
         """Newest-first dicts of the last ``n`` finished traces."""
@@ -355,6 +436,17 @@ class Tracer:
                 "p95_s": vals[min(len(vals) - 1, int(len(vals) * 0.95))],
             }
         return out
+
+
+def _on_gc(tracer_ref, phase: str, info: dict) -> None:
+    tracer = tracer_ref()
+    if tracer is not None:
+        tracer._on_gc_phase(phase)
+
+
+def _unhook(hook) -> None:
+    with contextlib.suppress(ValueError):
+        gc.callbacks.remove(hook)
 
 
 #: Shared fallback so call sites can write

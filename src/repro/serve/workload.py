@@ -890,9 +890,11 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
     spans (cache_lookup, plan_build, eval, null_chunk) fire while that
     trace is *activated* around the calls below, the shared coalesced
     group eval is timed once and attributed to every member as an ``eval``
-    span, and the finished trace's per-stage sums attach to the response
-    as ``timings``. Tracing off ⇒ all hooks are no-ops and ``timings``
-    stays None.
+    span (with a live ``repro.eval`` profiler annotation around the work),
+    and the trace's per-stage sums attach to the response as ``timings``.
+    A trace made here is finished here; one handed in on the workload (by
+    a server's submit or the HTTP edge) is finished by its maker. Tracing
+    off ⇒ all hooks are no-ops and ``timings`` stays None.
     """
     # reprolint: host-path
     # (Batch grouping/coalescing is host work: eager jnp assembly here
@@ -1036,9 +1038,12 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
             # engine-internal eval span is a no-op — the cost is counted
             # exactly once per trace.
             t0 = time.perf_counter() if tracer.enabled else 0.0
-            ys = [jnp.asarray(w.y) for _, w in members]
-            run = batcher.run_columns if spec.layout == "columns" else batcher.run_rows
-            outs = run(ys, lambda b: engine.eval_estimator(plan, b, estimator, owned=True, **opts))
+            with tracer.annotate("eval"):
+                ys = [jnp.asarray(w.y) for _, w in members]
+                run = batcher.run_columns if spec.layout == "columns" else batcher.run_rows
+                outs = run(
+                    ys, lambda b: engine.eval_estimator(plan, b, estimator, owned=True, **opts)
+                )
             if tracer.enabled:
                 dt = time.perf_counter() - t0
                 for i, _w in members:
@@ -1062,7 +1067,8 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
     for (key, contrast, diss, adj, c), (plan, members) in rsa_groups.items():
         try:
             t0 = time.perf_counter() if tracer.enabled else 0.0
-            rdms = _rsa_empirical(engine, key, plan, contrast, diss, adj, c, members)
+            with tracer.annotate("eval"):
+                rdms = _rsa_empirical(engine, key, plan, contrast, diss, adj, c, members)
             if tracer.enabled:
                 dt = time.perf_counter() - t0
                 for i, _w in members:
@@ -1105,7 +1111,8 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
             x_new = np.concatenate([np.asarray(b) for b in x_blocks]) if x_blocks else None
             drop_idx = np.concatenate(drops) if drops else None
             t0 = time.perf_counter() if tracer.enabled else 0.0
-            handle = update_dataset(members[0][1].dataset, x_new=x_new, drop_idx=drop_idx)
+            with tracer.annotate("plan_update"):
+                handle = update_dataset(members[0][1].dataset, x_new=x_new, drop_idx=drop_idx)
             if tracer.enabled:
                 dt = time.perf_counter() - t0
                 for i, _w in members:
@@ -1131,12 +1138,14 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
             except Exception as e:  # noqa: BLE001 - per-member encode
                 fail(i, e)
 
-    # -- close traces; attach per-stage sums to the responses --------------
+    # -- attach per-stage sums to the responses; close the traces made ----
+    # here (a trace handed in on the workload is closed by its creator) ---
     for i, resp in enumerate(responses):
         tr = traces[i]
         if tr is None:
             continue
-        tracer.finish(tr)
+        if trace_of(raw[i]) is not tr:
+            tracer.finish(tr)
         if resp is not None and not isinstance(resp, Exception):
             resp.timings = tr.timings()
     if release is not None:
@@ -1263,7 +1272,7 @@ def stream_workload(engine, workload, chunk: int = 64) -> Iterator[ProgressEvent
     The final "done" response carries ``timings`` like the batched path.
     """
     tracer = getattr(engine, "tracer", None) or NULL_TRACER
-    tr = trace_of(workload)
+    tr = handed = trace_of(workload)
     if tr is None and tracer.enabled:
         tr = tracer.trace()
     with tracer.activate(tr):
@@ -1286,9 +1295,12 @@ def stream_workload(engine, workload, chunk: int = 64) -> Iterator[ProgressEvent
         yield from _stream_update(engine, w, chunk, tracer, tr)
     else:
         # run_workloads counts the request, picks the trace up from the
-        # workload object, and attaches timings itself.
+        # workload object, and attaches timings itself; the trace is
+        # closed by whoever made it.
         attach_trace(w, tr)
         (resp,) = run_workloads(engine, [w])
+        if tr is not handed:
+            tracer.finish(tr)
         yield ProgressEvent("done", 1, 1, resp)
 
 

@@ -9,3 +9,8 @@ def instrument(tracer, tr, registry, dt):
     with tracer.span("plan_build"):  # allowed: in STAGES
         pass
     tr.add("encode", dt)  # allowed: in STAGES
+    with tracer.annotate("evaluate"):  # seeded: RL002 (annotation of no stage)
+        pass
+    tr.open_span("decode_wait")  # seeded: RL002
+    with tracer.annotate("executor_wait"):  # allowed: in STAGES
+        pass

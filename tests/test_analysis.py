@@ -54,6 +54,8 @@ def test_rl002_stage_vocabulary():
         ("RL002", 5),  # span("warp_speed")
         ("RL002", 7),  # add("decoed", ...)
         ("RL002", 8),  # observe(..., stage="telemetry")
+        ("RL002", 12),  # annotate("evaluate")
+        ("RL002", 14),  # open_span("decode_wait")
     ]
 
 
