@@ -911,6 +911,7 @@ class CVEngine:
                 self.labels_evaluated += b
             return out[..., 0] if squeeze else out
         padded, b = self._pad_rows(batch, owned=owned)
+        self._count_step2(plan, padded.shape[0], opts.get("num_classes", 0))
         with self.tracer.span("eval"):
             out = self.tracer.sync(fn(plan, padded)[:b])
         with self._lock:
@@ -1053,6 +1054,13 @@ class CVEngine:
             fn = self._perm_binary[(metric, adjust_bias)] = jax.jit(_eval)
         return fn
 
+    def _count_step2(self, plan: fastcv.CVPlan, rows: int, num_classes: int) -> None:
+        """Count the C×C step-2 eigenproblems of one multi-class dispatch:
+        one per (padded label vector, fold). ``num_classes`` 0: none."""
+        if num_classes:
+            self.metrics.inc("step2_solves_total", rows * plan.te_idx.shape[0],
+                             solver=multiclass.step2_solver(num_classes))
+
     def _perm_multiclass_fn(self, num_classes: int):
         fn = self._perm_multiclass.get(num_classes)
         if fn is None:
@@ -1144,7 +1152,9 @@ class CVEngine:
         with self.tracer.span("eval"):
             fn = self._perm_multiclass_fn(num_classes)
             identity = jnp.arange(y.shape[0], dtype=jnp.int32)[None]
-            return self.tracer.sync(fn(plan, y, self._pad_rows(identity, owned=True)[0])[0])
+            padded = self._pad_rows(identity, owned=True)[0]
+            self._count_step2(plan, padded.shape[0], num_classes)
+            return self.tracer.sync(fn(plan, y, padded)[0])
 
     def null_multiclass(
         self, plan: fastcv.CVPlan, y: jax.Array, perms: jax.Array, *, num_classes: int
@@ -1153,6 +1163,7 @@ class CVEngine:
         with self.tracer.span("null_chunk"):
             fn = self._perm_multiclass_fn(num_classes)
             padded, b = self._pad_rows(perms, owned=True)
+            self._count_step2(plan, padded.shape[0], num_classes)
             out = self.tracer.sync(fn(plan, y, padded)[:b])
         with self._lock:
             self.labels_evaluated += b
@@ -1211,7 +1222,9 @@ class CVEngine:
         t_gen = bucket_size(n_perm, self.config.buckets)
         with self.tracer.span("null_chunk"):
             perms = self.tracer.sync(perm_lib.permutation_indices(key, n, t_gen))
-            null = self.tracer.sync(fn(plan, y, self._pad_rows(perms, owned=True)[0])[:n_perm])
+            padded = self._pad_rows(perms, owned=True)[0]
+            self._count_step2(plan, padded.shape[0], num_classes)
+            null = self.tracer.sync(fn(plan, y, padded)[:n_perm])
         with self._lock:
             self.labels_evaluated += n_perm
         with self.tracer.span("null_chunk"):

@@ -96,6 +96,11 @@ METRICS = {
         "labels": ("op",),
         "help": "Incremental dataset updates applied, by operation",
     },
+    "step2_solves_total": {
+        "kind": "counter",
+        "labels": ("solver",),
+        "help": "Multi-class step-2 CxC eigenproblems dispatched (padded batch x folds), by solver",
+    },
     "stage_latency_seconds": {
         "kind": "histogram",
         "labels": ("stage",),

@@ -128,3 +128,19 @@ def test_fused_eval_compiles_for_v5e(one_chip, compiled_kernels, estimator):
         fn = multiclass.make_eval_multiclass(3, fused=True)
         plan, y = _plan_spec(one_chip, True), _spec((1, N), jnp.int32, one_chip)
     assert "tpu_custom_call" in _compile(fn, plan, y)
+
+
+def test_multiclass_null_eval_has_no_eigh_for_v5e(one_chip):
+    """The 3-class null eval the engine dispatches (1024 draws × K folds of
+    3 × 3 step-2 problems) solves them by elementwise Jacobi sweeps: the
+    compiled program holds no ``eigh`` custom call."""
+    from repro.serve import CVEngine
+
+    fn = CVEngine()._perm_multiclass_fn(3)
+    text = _compile(
+        fn,
+        _plan_spec(one_chip, True),
+        _spec((N,), jnp.int32, one_chip),
+        _spec((1024, N), jnp.int32, one_chip),
+    )
+    assert "Eigh" not in text
