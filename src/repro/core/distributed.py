@@ -19,6 +19,8 @@ permutations, problems) is sharded.
 from __future__ import annotations
 
 import contextlib
+import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +34,8 @@ __all__ = [
     "distributed_gram",
     "distributed_hat_matrix",
     "distributed_permutation_binary",
+    "mesh_null_program",
+    "whole_shards",
     "sharded_null_from_plan",
     "replicated",
     "sharded_problems",
@@ -56,8 +60,15 @@ def distributed_gram(x: jax.Array, mesh: Mesh, *, center: bool = True,
                      feature_axis: str = "model") -> jax.Array:
     """G_c = X_c X_cᵀ with X sharded (replicated_N, features/"model").
 
-    Local partial Gram per feature shard + one psum over the feature axis.
+    Local partial Gram per feature shard + one psum over the feature axis,
+    in one jitted program (``jit__mesh_gram``) per shape.
     """
+    with _ambient(mesh):
+        return _mesh_gram(x, mesh=mesh, center=center, feature_axis=feature_axis)
+
+
+@partial(jax.jit, static_argnames=("mesh", "center", "feature_axis"))
+def _mesh_gram(x, *, mesh, center, feature_axis):
     if center:
         x = x - jnp.mean(x, axis=0, keepdims=True)
 
@@ -65,12 +76,8 @@ def distributed_gram(x: jax.Array, mesh: Mesh, *, center: bool = True,
         g = jnp.matmul(x_shard, x_shard.T, precision=dot_precision(x_shard.dtype))
         return jax.lax.psum(g, feature_axis)
 
-    fn = jax.shard_map(
-        local_gram, mesh=mesh,
-        in_specs=P(None, feature_axis),
-        out_specs=P(None, None))
-    with _ambient(mesh):
-        return fn(x)
+    return jax.shard_map(local_gram, mesh=mesh, in_specs=P(None, feature_axis),
+                         out_specs=P(None, None))(x)
 
 
 def distributed_hat_matrix(x: jax.Array, lam: float, mesh: Mesh,
@@ -88,8 +95,6 @@ def distributed_permutation_binary(
 ):
     """Algorithm 1 at scale: Gram sharded over features, permutations over
     the DP axes. Returns PermutationResult-compatible (observed, null, p).
-
-    n_perm must divide by the product of perm-axis sizes (pad up if not).
     """
     from repro.core import permutation as perm_lib
 
@@ -100,17 +105,47 @@ def distributed_permutation_binary(
     dv_obs = fastcv.binary_dvals(plan, y, adjust_bias=adjust_bias)
     observed = perm_lib._fold_metric_binary(dv_obs, y[plan.te_idx], metric)
 
-    n_shards = 1
-    for a in perm_axes:
-        n_shards *= mesh.shape[a]
-    t_pad = -(-n_perm // n_shards) * n_shards
-    perms = perm_lib.permutation_indices(key, y.shape[0], t_pad)  # (T, N)
-
-    null = replicated(sharded_null_from_plan(plan, y, perms, mesh, metric=metric,
-                                             perm_axes=perm_axes,
-                                             adjust_bias=adjust_bias), mesh)[:n_perm]
+    perms = perm_lib.permutation_indices(key, y.shape[0], n_perm)  # (T, N)
+    null = mesh_null_program(mesh, metric=metric, perm_axes=perm_axes,
+                             adjust_bias=adjust_bias)(plan, y, perms)
     return perm_lib.PermutationResult(observed, null,
                                       perm_lib.p_value(observed, null))
+
+
+def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
+                      perm_axes: tuple = ("data",), adjust_bias: bool = True):
+    """One jitted program (``jit__mesh_null``): (plan, y (N,), perms (B, N))
+    → (B,) null metrics, replicated on every device of ``mesh``.
+
+    Inside the program the draws are padded (with the last draw) to a whole
+    number of shards over ``perm_axes``, evaluated by
+    :func:`sharded_null_from_plan`, gathered to a replicated result (the
+    all-gather is an op of the program) and cut back to B. The returned
+    callable makes ``mesh`` ambient around the call, which the body's
+    gathers need; a caller holds on to it so each shape compiles once.
+    """
+    def _mesh_null(plan, y, perms):
+        b = perms.shape[0]
+        t_pad = whole_shards(b, mesh, perm_axes)
+        if t_pad > b:
+            perms = jnp.pad(perms, ((0, t_pad - b), (0, 0)), mode="edge")
+        null = sharded_null_from_plan(plan, y, perms, mesh, metric=metric,
+                                      perm_axes=perm_axes, adjust_bias=adjust_bias)
+        return replicated(null, mesh)[:b]
+
+    program = jax.jit(_mesh_null, out_shardings=NamedSharding(mesh, P()))
+
+    def call(plan, y, perms):
+        with _ambient(mesh):
+            return program(plan, y, perms)
+
+    return call
+
+
+def whole_shards(b: int, mesh: Mesh, perm_axes: tuple) -> int:
+    """``b`` draws rounded up to a whole number of shards over ``perm_axes``."""
+    n_shards = math.prod(mesh.shape[a] for a in perm_axes)
+    return -(-b // n_shards) * n_shards
 
 
 def sharded_null_from_plan(plan: fastcv.CVPlan, y: jax.Array,
@@ -119,14 +154,14 @@ def sharded_null_from_plan(plan: fastcv.CVPlan, y: jax.Array,
                            perm_axes: tuple = ("data",),
                            adjust_bias: bool = True) -> jax.Array:
     """Null-distribution metrics for ``perms`` (T, N), T sharded over
-    ``perm_axes``; the plan (hat matrix + fold factors) is replicated.
-    The (T,) result stays sharded over ``perm_axes``; see :func:`replicated`
-    before slicing it.
+    ``perm_axes`` (T a whole number of shards); the plan (hat matrix + fold
+    factors) and ``y`` are replicated. The (T,) result stays sharded over
+    ``perm_axes``: gather it with :func:`replicated` before slicing it.
 
-    This is the serve engine's distributed permutation path: the plan is
-    built once (possibly via :func:`distributed_gram`) and every batch of
-    permutation requests fans out over the mesh's data-parallel axes.
-    The plan and ``y`` enter as replicated operands, not closures: a
+    This is the body of :func:`mesh_null_program`, the serve engine's mesh
+    null path, which pads, gathers and slices around it in one program.
+    Called on its own, outside a jit, every op dispatches by itself. The
+    plan and ``y`` enter as replicated operands, not closures: a
     closed-over array sharded on ``Explicit`` mesh axes (what
     ``jax.make_mesh`` gives) is rejected by ``shard_map``, and the body's
     gathers need the mesh set as the ambient one to place their indices.
@@ -146,7 +181,8 @@ def sharded_null_from_plan(plan: fastcv.CVPlan, y: jax.Array,
 
 
 def replicated(x: jax.Array, mesh: Mesh) -> jax.Array:
-    """``x`` on every device of ``mesh``, unsharded.
+    """``x`` on every device of ``mesh``, unsharded: inside a jit, an
+    all-gather of a sharded ``x``; outside, a transfer.
 
     A sharded null must be gathered before a prefix is sliced off it: on
     ``Explicit`` mesh axes a slice that cuts across shards has no

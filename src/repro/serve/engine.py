@@ -54,13 +54,16 @@ sub-request granularity — the streaming front-end
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
+from collections.abc import Mapping
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import fastcv, metrics, multiclass, tuning
 from repro.core import permutation as perm_lib
@@ -118,8 +121,10 @@ class EngineConfig:
     cache_bytes: PlanCache byte budget.
     gram_impl:   "auto" (Pallas kernel on TPU, plain XLA elsewhere),
                  "xla", "pallas", or "distributed" (requires ``mesh``).
-    mesh:        optional jax Mesh; enables distributed plan builds and
-                 mesh-sharded permutation batches.
+    mesh:        optional jax Mesh, or a mapping of axis name to size
+                 (``{"data": 2, "model": 2}``: a mesh over the first
+                 ``prod(sizes)`` devices); enables distributed plan builds
+                 and mesh-sharded permutation batches.
     feature_axis / perm_axes: mesh axis names for the feature-sharded Gram
                  reduction and the permutation fan-out respectively.
     donate:      donate label-batch buffers to the jitted evals. Off by
@@ -168,6 +173,12 @@ class EngineConfig:
     store_bytes: int = 4 << 30
 
     def __post_init__(self):
+        if isinstance(self.mesh, Mapping):
+            sizes = tuple(int(v) for v in self.mesh.values())
+            mesh = jax.make_mesh(sizes, tuple(self.mesh),
+                                 devices=jax.devices()[:math.prod(sizes)])
+            object.__setattr__(self, "mesh", mesh)
+        object.__setattr__(self, "perm_axes", tuple(self.perm_axes))
         if self.gram_impl not in _GRAM_IMPLS:
             raise ValueError(f"gram_impl must be one of {_GRAM_IMPLS}")
         if self.gram_impl == "distributed" and self.mesh is None:
@@ -222,6 +233,7 @@ class CVEngine:
         # program with the wrong aliasing or kernel route.
         self._evals = {}  # (eval_key, static opts, donate, fused) -> jit
         self._perm_binary = {}  # (metric, adjust_bias) -> jit -> (B,)
+        self._mesh_null = {}  # (metric, adjust_bias) -> mesh jit -> (B,)
         self._perm_multiclass = {}  # num_classes -> jit -> (B,)
         self._rsa_pairs = {}  # (dissim, adjust_bias, donate, fused) -> jit
         self._rsa_score = {}  # method -> jit[(emp, models) -> (M,)]
@@ -427,6 +439,10 @@ class CVEngine:
                               precision=self.config.precision)
         rec = self._datasets.get(key)
         if rec is None:
+            if self.config.mesh is not None:
+                # features over the feature axis, as the sharded Gram reads them
+                x = jax.device_put(x, NamedSharding(
+                    self.config.mesh, PartitionSpec(None, self.config.feature_axis)))
             handle = DatasetHandle(
                 key=key, n=int(x.shape[0]), p=int(x.shape[1]), lam=float(lam), mode=mode
             )
@@ -1054,6 +1070,19 @@ class CVEngine:
             fn = self._perm_binary[(metric, adjust_bias)] = jax.jit(_eval)
         return fn
 
+    def _mesh_null_fn(self, metric: str, adjust_bias: bool):
+        """The mesh analogue of :meth:`_perm_binary_fn`: one program
+        (``jit__mesh_null``) that pads the draws to whole shards, evaluates
+        them over ``perm_axes``, gathers the null and slices it back."""
+        fn = self._mesh_null.get((metric, adjust_bias))
+        if fn is None:
+            from repro.core.distributed import mesh_null_program
+
+            fn = self._mesh_null[(metric, adjust_bias)] = mesh_null_program(
+                self.config.mesh, metric=metric, perm_axes=self.config.perm_axes,
+                adjust_bias=adjust_bias)
+        return fn
+
     def _count_step2(self, plan: fastcv.CVPlan, rows: int, num_classes: int) -> None:
         """Count the C×C step-2 eigenproblems of one multi-class dispatch:
         one per (padded label vector, fold). ``num_classes`` 0: none."""
@@ -1102,48 +1131,45 @@ class CVEngine:
         *,
         metric: str = "accuracy",
         adjust_bias: bool = True,
+        requested: Optional[int] = None,
     ) -> jax.Array:
         """Null metrics for an explicit (B, N) permutation batch → (B,).
 
         The chunk-level building block under both :meth:`permutation_binary`
         and the streaming front-end. On a mesh-configured engine the batch
-        shards over ``perm_axes`` via ``sharded_null_from_plan`` (padded up
-        to a whole number of shards, trimmed back) — so *streamed* null
-        chunks use the mesh exactly like monolithic requests, with
-        identical draws. Locally, the batch pads up to a shape bucket and
-        repeats never recompile.
+        shards over ``perm_axes`` in one jitted program per shape (padded
+        up to a whole number of shards, gathered, trimmed back) — so
+        *streamed* null chunks use the mesh exactly like monolithic
+        requests, with identical draws. Locally, the batch pads up to a
+        shape bucket and repeats never recompile.
+
+        ``requested`` is how many leading rows of ``perms`` were asked for
+        (all of them by default); the rest, and the rows the path pads on,
+        count as padding in ``null_pad_draws_total``.
         """
         b = perms.shape[0]
+        requested = b if requested is None else requested
         with self.tracer.span("null_chunk"):
             if not adjust_bias:
                 plan = self._strip_train(plan)
             y = y.astype(plan.h.dtype)
             if self.config.mesh is not None:
-                from repro.core.distributed import replicated, sharded_null_from_plan
+                from repro.core.distributed import whole_shards
 
-                n_shards = 1
-                for a in self.config.perm_axes:
-                    n_shards *= self.config.mesh.shape[a]
-                t_pad = -(-b // n_shards) * n_shards
-                if t_pad > b:
-                    perms = jnp.pad(perms, ((0, t_pad - b), (0, 0)), mode="edge")
-                out = sharded_null_from_plan(
-                    plan,
-                    y,
-                    perms,
-                    self.config.mesh,
-                    metric=metric,
-                    perm_axes=self.config.perm_axes,
-                    adjust_bias=adjust_bias,
-                )
-                # callers slice prefixes (n_perm < bucket): gather first
-                out = replicated(out, self.config.mesh)[:b]
+                path = "mesh"
+                rows = whole_shards(b, self.config.mesh, self.config.perm_axes)
+                out = self._mesh_null_fn(metric, adjust_bias)(plan, y, perms)
             else:
+                path = "local"
                 fn = self._perm_binary_fn(metric, adjust_bias)
-                out = fn(plan, y, self._pad_rows(perms, owned=True)[0])[:b]
+                padded = self._pad_rows(perms, owned=True)[0]
+                rows = padded.shape[0]
+                out = fn(plan, y, padded)[:b]
             self.tracer.sync(out)
+        self.metrics.inc("null_draws_total", requested, path=path)
+        self.metrics.inc("null_pad_draws_total", rows - requested, path=path)
         with self._lock:
-            self.labels_evaluated += b
+            self.labels_evaluated += requested
         return out
 
     def observed_multiclass(
@@ -1183,7 +1209,8 @@ class CVEngine:
 
         With a mesh configured, the permutation batch shards over the
         mesh's ``perm_axes``; otherwise it runs through the bucketed local
-        eval path (padded to a static shape, so repeats never recompile).
+        eval path. Either way the draws are generated at the bucket size,
+        so repeats never recompile and both paths evaluate the same draws.
         """
         n = y.shape[0]
         observed = self.observed_binary(plan, y, metric=metric, adjust_bias=adjust_bias)
@@ -1196,12 +1223,14 @@ class CVEngine:
         # stage-sum ≈ end-to-end acceptance invariant.
         t_gen = bucket_size(n_perm, self.config.buckets)
         with self.tracer.span("null_chunk"):
+            if self.config.mesh is not None:
+                # every chip draws them all and keeps its shard: no transfer
+                key = jax.device_put(key, NamedSharding(self.config.mesh, PartitionSpec()))
             perms = self.tracer.sync(perm_lib.permutation_indices(key, n, t_gen))
-        null = self.null_binary(plan, y, perms, metric=metric, adjust_bias=adjust_bias)[:n_perm]
-        # null_binary counted the bucketed batch; this API's contract (and
-        # the multiclass path) counts the *requested* draws only.
-        with self._lock:
-            self.labels_evaluated -= t_gen - n_perm
+        # this API's contract (and the multiclass path) counts the
+        # *requested* draws only
+        null = self.null_binary(plan, y, perms, metric=metric, adjust_bias=adjust_bias,
+                                requested=n_perm)[:n_perm]
         with self.tracer.span("null_chunk"):
             p = self.tracer.sync(perm_lib.p_value(observed, null))
         return perm_lib.PermutationResult(observed, null, p)

@@ -101,6 +101,16 @@ METRICS = {
         "labels": ("solver",),
         "help": "Multi-class step-2 CxC eigenproblems dispatched (padded batch x folds), by solver",
     },
+    "null_draws_total": {
+        "kind": "counter",
+        "labels": ("path",),
+        "help": "Binary permutation-null draws requested, by path (mesh or local)",
+    },
+    "null_pad_draws_total": {
+        "kind": "counter",
+        "labels": ("path",),
+        "help": "Binary permutation-null rows evaluated as padding (to the bucket and to whole shards), by path",
+    },
     "stage_latency_seconds": {
         "kind": "histogram",
         "labels": ("stage",),

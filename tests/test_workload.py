@@ -545,19 +545,25 @@ def test_sync_stream_matches_monolithic(problem):
 
 
 def test_mesh_engine_streams_sharded_null_chunks(problem, monkeypatch):
-    """ROADMAP gap: streamed permutation chunks must route through
-    sharded_null_from_plan on a mesh-configured engine, with draws
-    identical to the monolithic (and local) paths."""
+    """ROADMAP gap: streamed permutation chunks must route through the
+    mesh null program (sharded_null_from_plan inside one jit) on a
+    mesh-configured engine, with draws identical to the monolithic (and
+    local) paths."""
     from repro.core import distributed
 
     calls = {"n": 0}
-    real = distributed.sharded_null_from_plan
+    real = distributed.mesh_null_program
 
     def counting(*args, **kwargs):
-        calls["n"] += 1
-        return real(*args, **kwargs)
+        program = real(*args, **kwargs)
 
-    monkeypatch.setattr(distributed, "sharded_null_from_plan", counting)
+        def call(*a):
+            calls["n"] += 1
+            return program(*a)
+
+        return call
+
+    monkeypatch.setattr(distributed, "mesh_null_program", counting)
 
     x, y, _, f = problem
     mesh = jax.make_mesh((1, 1), ("data", "model"))
