@@ -411,7 +411,7 @@ def four_chip(n_trials: int = N_TRIALS, n_perm: int = N_PERM, seed: int = SEED) 
     r_one = Client(one).submit(Workload(dataset=h_one, **w))
     # the engine's null, before it is gathered for slicing: T over "data"
     null_shards = sharded_null_from_plan(
-        plan_mesh, jnp.asarray(y), perm_lib.permutation_indices(jax.random.PRNGKey(seed), n, 8),
+        plan_mesh, perm_lib.permutation_draws(jax.random.PRNGKey(seed), jnp.asarray(y), 8),
         mesh)
     trial = 1.0 / np.asarray(folds.te_idx).size
     dnull = float(np.abs(np.asarray(r_mesh.null) - np.asarray(r_one.null)).max())

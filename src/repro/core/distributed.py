@@ -105,17 +105,18 @@ def distributed_permutation_binary(
     dv_obs = fastcv.binary_dvals(plan, y, adjust_bias=adjust_bias)
     observed = perm_lib._fold_metric_binary(dv_obs, y[plan.te_idx], metric)
 
-    perms = perm_lib.permutation_indices(key, y.shape[0], n_perm)  # (T, N)
+    y_perm = perm_lib.permutation_draws(key, y, n_perm)  # (T, N)
     null = mesh_null_program(mesh, metric=metric, perm_axes=perm_axes,
-                             adjust_bias=adjust_bias)(plan, y, perms)
+                             adjust_bias=adjust_bias)(plan, y_perm)
     return perm_lib.PermutationResult(observed, null,
                                       perm_lib.p_value(observed, null))
 
 
 def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
                       perm_axes: tuple = ("data",), adjust_bias: bool = True):
-    """One jitted program (``jit__mesh_null``): (plan, y (N,), perms (B, N))
-    → (B,) null metrics, replicated on every device of ``mesh``.
+    """One jitted program (``jit__mesh_null``): (plan, y_perm (B, N)) → (B,)
+    null metrics of the permuted label rows, replicated on every device of
+    ``mesh``.
 
     Inside the program the draws are padded (with the last draw) to a whole
     number of shards over ``perm_axes``, evaluated by
@@ -124,20 +125,20 @@ def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
     callable makes ``mesh`` ambient around the call, which the body's
     gathers need; a caller holds on to it so each shape compiles once.
     """
-    def _mesh_null(plan, y, perms):
-        b = perms.shape[0]
+    def _mesh_null(plan, y_perm):
+        b = y_perm.shape[0]
         t_pad = whole_shards(b, mesh, perm_axes)
         if t_pad > b:
-            perms = jnp.pad(perms, ((0, t_pad - b), (0, 0)), mode="edge")
-        null = sharded_null_from_plan(plan, y, perms, mesh, metric=metric,
+            y_perm = jnp.pad(y_perm, ((0, t_pad - b), (0, 0)), mode="edge")
+        null = sharded_null_from_plan(plan, y_perm, mesh, metric=metric,
                                       perm_axes=perm_axes, adjust_bias=adjust_bias)
         return replicated(null, mesh)[:b]
 
     program = jax.jit(_mesh_null, out_shardings=NamedSharding(mesh, P()))
 
-    def call(plan, y, perms):
+    def call(plan, y_perm):
         with _ambient(mesh):
-            return program(plan, y, perms)
+            return program(plan, y_perm)
 
     return call
 
@@ -148,36 +149,33 @@ def whole_shards(b: int, mesh: Mesh, perm_axes: tuple) -> int:
     return -(-b // n_shards) * n_shards
 
 
-def sharded_null_from_plan(plan: fastcv.CVPlan, y: jax.Array,
-                           perms: jax.Array, mesh: Mesh, *,
+def sharded_null_from_plan(plan: fastcv.CVPlan, y_perm: jax.Array, mesh: Mesh, *,
                            metric: str = "accuracy",
                            perm_axes: tuple = ("data",),
                            adjust_bias: bool = True) -> jax.Array:
-    """Null-distribution metrics for ``perms`` (T, N), T sharded over
-    ``perm_axes`` (T a whole number of shards); the plan (hat matrix + fold
-    factors) and ``y`` are replicated. The (T,) result stays sharded over
-    ``perm_axes``: gather it with :func:`replicated` before slicing it.
+    """Null-distribution metrics for the permuted label rows ``y_perm``
+    (T, N), T sharded over ``perm_axes`` (T a whole number of shards); the
+    plan (hat matrix + fold factors) is replicated. Each shard runs
+    :func:`repro.core.permutation.null_binary_metrics` on its rows. The
+    (T,) result stays sharded over ``perm_axes``: gather it with
+    :func:`replicated` before slicing it.
 
     This is the body of :func:`mesh_null_program`, the serve engine's mesh
     null path, which pads, gathers and slices around it in one program.
     Called on its own, outside a jit, every op dispatches by itself. The
-    plan and ``y`` enter as replicated operands, not closures: a
-    closed-over array sharded on ``Explicit`` mesh axes (what
-    ``jax.make_mesh`` gives) is rejected by ``shard_map``, and the body's
-    gathers need the mesh set as the ambient one to place their indices.
+    plan enters as a replicated operand, not a closure: a closed-over
+    array sharded on ``Explicit`` mesh axes (what ``jax.make_mesh`` gives)
+    is rejected by ``shard_map``, and the body's gathers need the mesh set
+    as the ambient one to place their indices.
     """
     from repro.core import permutation as perm_lib
 
-    def shard_fn(plan, y, perm_shard):
-        yp = y[perm_shard].T                                   # (N, T_local)
-        dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
-        y_te = yp[plan.te_idx]
-        return perm_lib._fold_metric_binary(dv, y_te, metric)  # (T_local,)
-
-    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(), P(), P(perm_axes)),
+    shard_fn = partial(perm_lib.null_binary_metrics, metric=metric,
+                       adjust_bias=adjust_bias)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(), P(perm_axes)),
                        out_specs=P(perm_axes))
     with _ambient(mesh):
-        return fn(plan, y, perms)
+        return fn(plan, y_perm)
 
 
 def replicated(x: jax.Array, mesh: Mesh) -> jax.Array:

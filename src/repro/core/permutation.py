@@ -6,7 +6,9 @@ permutation σ only needs ŷ = H yσ and the per-fold solves against the
 shared across permutations (O(m³) → O(m²) per permutation per fold) and
 permutations are processed in static-size chunks via ``lax.map`` so T can
 be large without exhausting memory; chunks are the unit the distributed
-engine shards over the ("pod", "data") mesh axes.
+engine shards over the ("pod", "data") mesh axes. A draw is the permuted
+label row itself (:func:`permutation_draws`), so a null reads its labels
+directly instead of gathering them through an index row.
 
 Standard-approach baselines (retrain K models per permutation) are provided
 for the benchmark comparison (Fig. 3 right panels, Fig. 4).
@@ -25,7 +27,9 @@ from repro.core.folds import Folds
 
 __all__ = [
     "PermutationResult",
+    "permutation_draws",
     "permutation_indices",
+    "null_binary_metrics",
     "analytical_permutation_binary",
     "standard_permutation_binary",
     "analytical_permutation_multiclass",
@@ -46,19 +50,32 @@ def p_value(observed: jax.Array, null: jax.Array) -> jax.Array:
     return (1.0 + jnp.sum(null >= observed)) / (1.0 + t)
 
 
-@partial(jax.jit, static_argnames=("n", "n_perm"))
-def permutation_indices(key: jax.Array, n: int, n_perm: int) -> jax.Array:
-    """(T, N) independent label permutations.
+@partial(jax.jit, static_argnames=("n_perm",))
+def permutation_draws(key: jax.Array, payload: jax.Array, n_perm: int) -> jax.Array:
+    """(T, len(payload)) rows: draw t is ``payload`` shuffled under the key
+    ``fold_in(key, t)``.
 
-    Jitted (the serve engine regenerates these per request, so dispatch
-    overhead matters) and *prefix-stable*: permutation t depends only on
-    (key, t) via ``fold_in``, so requesting a larger T — e.g. the engine
-    rounding T up to a shape bucket — yields the same leading rows as a
-    direct call. That keeps engine null distributions identical to the
-    library's for any shared key.
+    Jitted (the serve engine draws per request, so dispatch overhead
+    matters) and *prefix-stable*: draw t depends only on (key, t), so
+    requesting a larger T — e.g. the engine rounding T up to a shape
+    bucket — yields the same leading rows as a direct call. That keeps
+    engine null distributions identical to the library's for any shared
+    key. The shuffle is a stable sort of ``payload`` by random keys, so its
+    moves depend on the keys alone: ``permutation_draws(key, y, T)`` equals
+    ``y[permutation_indices(key, len(y), T)]`` exactly, and a null reads
+    its permuted labels without gathering them one element at a time.
     """
     keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(jnp.arange(n_perm))
-    return jax.vmap(lambda k: jax.random.permutation(k, n))(keys)
+    return jax.vmap(lambda k: jax.random.permutation(k, payload))(keys)
+
+
+@partial(jax.jit, static_argnames=("n", "n_perm"))
+def permutation_indices(key: jax.Array, n: int, n_perm: int) -> jax.Array:
+    """(T, N) index rows: :func:`permutation_draws` of ``arange(n)``.
+
+    For nulls that permute something other than a label row (RSA permutes
+    an RDM's rows and columns) and for references that index labels."""
+    return permutation_draws(key, jnp.arange(n), n_perm)
 
 
 def _fold_metric_binary(dvals, y_te, metric: str):
@@ -74,6 +91,17 @@ def _fold_metric_binary(dvals, y_te, metric: str):
     raise ValueError(f"unknown metric {metric!r}")
 
 
+def null_binary_metrics(plan: fastcv.CVPlan, y_perm: jax.Array, *,
+                        metric: str = "accuracy", adjust_bias: bool = True) -> jax.Array:
+    """(B,) binary metrics of the permuted label rows ``y_perm`` (B, N).
+
+    The one null evaluation: the serve engine's local program, the mesh
+    program's per-shard body and the library's chunks all call it."""
+    yp = y_perm.T                                             # (N, B)
+    dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
+    return _fold_metric_binary(dv, yp[plan.te_idx], metric)
+
+
 def analytical_permutation_binary(
     x: jax.Array, y: jax.Array, folds: Folds, lam: float, n_perm: int,
     key: jax.Array, metric: str = "accuracy", mode: str = "auto",
@@ -86,20 +114,17 @@ def analytical_permutation_binary(
     dv_obs = fastcv.binary_dvals(plan, y, adjust_bias=adjust_bias)
     observed = _fold_metric_binary(dv_obs, y[plan.te_idx], metric)
 
-    perms = permutation_indices(key, y.shape[0], n_perm)      # (T, N)
+    y_perm = permutation_draws(key, y, n_perm)               # (T, N)
     chunk = min(chunk, n_perm)
     n_chunks = -(-n_perm // chunk)
     pad = n_chunks * chunk - n_perm
-    perms = jnp.pad(perms, ((0, pad), (0, 0)), mode="edge")
-    perms = perms.reshape(n_chunks, chunk, -1)
+    y_perm = jnp.pad(y_perm, ((0, pad), (0, 0)), mode="edge")
+    y_perm = y_perm.reshape(n_chunks, chunk, -1)
 
-    def one_chunk(perm_chunk):
-        yp = y[perm_chunk].T                                  # (N, chunk)
-        dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
-        y_te = yp[plan.te_idx]                                # (K, m, chunk)
-        return _fold_metric_binary(dv, y_te, metric)          # (chunk,)
+    def one_chunk(rows):
+        return null_binary_metrics(plan, rows, metric=metric, adjust_bias=adjust_bias)
 
-    null = jax.lax.map(one_chunk, perms).reshape(-1)[:n_perm]
+    null = jax.lax.map(one_chunk, y_perm).reshape(-1)[:n_perm]
     return PermutationResult(observed, null, p_value(observed, null))
 
 
@@ -111,16 +136,14 @@ def standard_permutation_binary(
     y = y.astype(x.dtype)
     dv_obs, y_te = lda.standard_cv_binary(x, y, folds, lam=lam)
     observed = _fold_metric_binary(dv_obs, y_te, metric)
-    perms = permutation_indices(key, y.shape[0], n_perm)
 
     @jax.jit
-    def one_perm(perm):
-        yp = y[perm]
+    def one_perm(yp):
         dv, yte = lda._standard_cv_binary_jit(
             x, yp, folds.te_idx, folds.tr_idx, jnp.asarray(lam, x.dtype), "lda")
         return _fold_metric_binary(dv, yte, metric)
 
-    null = jax.lax.map(one_perm, perms)
+    null = jax.lax.map(one_perm, permutation_draws(key, y, n_perm))
     return PermutationResult(observed, null, p_value(observed, null))
 
 
@@ -137,12 +160,12 @@ def analytical_permutation_multiclass(
         x, y, folds, num_classes, lam, mode=mode, plan=plan)
     observed = metrics.multiclass_accuracy(pred_obs, y_te_obs)
 
-    perms = permutation_indices(key, y.shape[0], n_perm)
+    y_perm = permutation_draws(key, y, n_perm)
     chunk = min(chunk, n_perm)
     n_chunks = -(-n_perm // chunk)
     pad = n_chunks * chunk - n_perm
-    perms = jnp.pad(perms, ((0, pad), (0, 0)), mode="edge")
-    perms = perms.reshape(n_chunks, chunk, -1)
+    y_perm = jnp.pad(y_perm, ((0, pad), (0, 0)), mode="edge")
+    y_perm = y_perm.reshape(n_chunks, chunk, -1)
 
     def one_perm(yp):
         y1h = multiclass.onehot(yp, num_classes, dtype=dtype)
@@ -152,10 +175,7 @@ def analytical_permutation_multiclass(
             y_dot_te, y_dot_tr, y1h_tr, dtype)
         return metrics.multiclass_accuracy(preds, yp[plan.te_idx])
 
-    def one_chunk(perm_chunk):
-        return jax.vmap(lambda p: one_perm(y[p]))(perm_chunk)
-
-    null = jax.lax.map(one_chunk, perms).reshape(-1)[:n_perm]
+    null = jax.lax.map(jax.vmap(one_perm), y_perm).reshape(-1)[:n_perm]
     return PermutationResult(observed, null, p_value(observed, null))
 
 
@@ -167,15 +187,13 @@ def standard_permutation_multiclass(
     pred_obs, y_te_obs = multiclass.standard_cv_multiclass(
         x, y, folds, num_classes, lam)
     observed = metrics.multiclass_accuracy(pred_obs, y_te_obs)
-    perms = permutation_indices(key, y.shape[0], n_perm)
 
     @jax.jit
-    def one_perm(perm):
-        yp = y[perm]
+    def one_perm(yp):
         pred, yte = multiclass._standard_cv_multiclass_jit(
             x, yp, folds.te_idx, folds.tr_idx, jnp.asarray(lam, x.dtype),
             num_classes)
         return metrics.multiclass_accuracy(pred, yte)
 
-    null = jax.lax.map(one_perm, perms)
+    null = jax.lax.map(one_perm, permutation_draws(key, y, n_perm))
     return PermutationResult(observed, null, p_value(observed, null))

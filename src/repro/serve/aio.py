@@ -31,7 +31,7 @@ service:
   (``engine.null_binary`` routes through ``sharded_null_from_plan``).
 
 The streamed permutations are the same draws the monolithic path uses
-(``permutation_indices`` is prefix-stable under bucket rounding), so a
+(``permutation_draws`` is prefix-stable under bucket rounding), so a
 stream's final ``done`` payload matches the one-shot response up to
 padded-shape rounding.
 """

@@ -825,12 +825,13 @@ class CVEngine:
                 rows = jnp.tile(y_mc[None, :], (b, 1))
                 outs.append(self.eval_multiclass(plan, rows, num_classes))
             if "permutation" in tasks:
-                perms = perm_lib.permutation_indices(jax.random.PRNGKey(0), n, b)
+                draws = perm_lib.permutation_draws(jax.random.PRNGKey(0), y_bin, b)
                 outs.append(
-                    self.null_binary(plan, y_bin, perms, metric=metric, adjust_bias=adjust_bias)
+                    self.null_binary(plan, draws, metric=metric, adjust_bias=adjust_bias)
                 )
                 if num_classes >= 2:  # mirrors the observed_multiclass gate above
-                    outs.append(self.null_multiclass(plan, y_mc, perms, num_classes=num_classes))
+                    draws = perm_lib.permutation_draws(jax.random.PRNGKey(0), y_mc, b)
+                    outs.append(self.null_multiclass(plan, draws, num_classes=num_classes))
             if "rsa" in tasks:
                 cols = jnp.tile(y_bin[:, None], (1, b))
                 outs.append(self.eval_rsa_pairs(plan, cols, dissimilarity, adjust_bias))
@@ -1055,17 +1056,13 @@ class CVEngine:
     # ------------------------------------------------------------------
 
     def _perm_binary_fn(self, metric: str, adjust_bias: bool):
-        """jit[(plan, y (N,), perms (B, N)) -> (B,) metrics].
-
-        The label gather lives *inside* the jit so the permuted (N, B)
-        label matrix is fused away rather than materialised per request."""
+        """jit[(plan, y_perm (B, N)) -> (B,) metrics] of permuted label rows."""
         fn = self._perm_binary.get((metric, adjust_bias))
         if fn is None:
 
-            def _eval(plan, y, perms):
-                yp = y[perms].T  # (N, B)
-                dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
-                return perm_lib._fold_metric_binary(dv, yp[plan.te_idx], metric)
+            def _eval(plan, y_perm):
+                return perm_lib.null_binary_metrics(plan, y_perm, metric=metric,
+                                                    adjust_bias=adjust_bias)
 
             fn = self._perm_binary[(metric, adjust_bias)] = jax.jit(_eval)
         return fn
@@ -1091,11 +1088,11 @@ class CVEngine:
                              solver=multiclass.step2_solver(num_classes))
 
     def _perm_multiclass_fn(self, num_classes: int):
+        """jit[(plan, y_rows (B, N) int) -> (B,) accuracies] of label rows."""
         fn = self._perm_multiclass.get(num_classes)
         if fn is None:
 
-            def _eval(plan, y, perms):
-                y_rows = y[perms]  # (B, N)
+            def _eval(plan, y_rows):
                 preds = multiclass.batch_predict(plan, y_rows, num_classes)
                 y_te = y_rows[:, plan.te_idx]  # (B, K, m)
                 return jax.vmap(metrics.multiclass_accuracy)(preds, y_te)
@@ -1112,28 +1109,26 @@ class CVEngine:
         adjust_bias: bool = True,
     ) -> jax.Array:
         """Observed (unpermuted) binary metric through the permutation path."""
-        # The span covers the dispatch preamble (dtype cast, identity
-        # batch, padding) too — each is a device dispatch that would
-        # otherwise show up as an untraced gap in the span timeline.
+        # The span covers the dispatch preamble (dtype cast, padding) too —
+        # each is a device dispatch that would otherwise show up as an
+        # untraced gap in the span timeline.
         with self.tracer.span("eval"):
             if not adjust_bias:
                 plan = self._strip_train(plan)
             y = y.astype(plan.h.dtype)
             fn = self._perm_binary_fn(metric, adjust_bias)
-            identity = jnp.arange(y.shape[0], dtype=jnp.int32)[None]
-            return self.tracer.sync(fn(plan, y, self._pad_rows(identity, owned=True)[0])[0])
+            return self.tracer.sync(fn(plan, self._pad_rows(y[None], owned=True)[0])[0])
 
     def null_binary(
         self,
         plan: fastcv.CVPlan,
-        y: jax.Array,
-        perms: jax.Array,
+        y_perm: jax.Array,
         *,
         metric: str = "accuracy",
         adjust_bias: bool = True,
         requested: Optional[int] = None,
     ) -> jax.Array:
-        """Null metrics for an explicit (B, N) permutation batch → (B,).
+        """Null metrics for a (B, N) batch of permuted label rows → (B,).
 
         The chunk-level building block under both :meth:`permutation_binary`
         and the streaming front-end. On a mesh-configured engine the batch
@@ -1143,56 +1138,60 @@ class CVEngine:
         requests, with identical draws. Locally, the batch pads up to a
         shape bucket and repeats never recompile.
 
-        ``requested`` is how many leading rows of ``perms`` were asked for
+        ``requested`` is how many leading rows of ``y_perm`` were asked for
         (all of them by default); the rest, and the rows the path pads on,
         count as padding in ``null_pad_draws_total``.
         """
-        b = perms.shape[0]
-        requested = b if requested is None else requested
+        b = y_perm.shape[0]
         with self.tracer.span("null_chunk"):
             if not adjust_bias:
                 plan = self._strip_train(plan)
-            y = y.astype(plan.h.dtype)
+            y_perm = y_perm.astype(plan.h.dtype)
             if self.config.mesh is not None:
                 from repro.core.distributed import whole_shards
 
                 path = "mesh"
                 rows = whole_shards(b, self.config.mesh, self.config.perm_axes)
-                out = self._mesh_null_fn(metric, adjust_bias)(plan, y, perms)
+                out = self._mesh_null_fn(metric, adjust_bias)(plan, y_perm)
             else:
                 path = "local"
                 fn = self._perm_binary_fn(metric, adjust_bias)
-                padded = self._pad_rows(perms, owned=True)[0]
+                padded = self._pad_rows(y_perm, owned=True)[0]
                 rows = padded.shape[0]
-                out = fn(plan, y, padded)[:b]
+                out = fn(plan, padded)[:b]
             self.tracer.sync(out)
+        self._count_null(path, b if requested is None else requested, rows)
+        return out
+
+    def _count_null(self, path: str, requested: int, rows: int) -> None:
+        """Count one null dispatch: ``requested`` draws asked for, of
+        ``rows`` evaluated (the rest padding)."""
         self.metrics.inc("null_draws_total", requested, path=path)
         self.metrics.inc("null_pad_draws_total", rows - requested, path=path)
         with self._lock:
             self.labels_evaluated += requested
-        return out
 
     def observed_multiclass(
         self, plan: fastcv.CVPlan, y: jax.Array, *, num_classes: int
     ) -> jax.Array:
         with self.tracer.span("eval"):
             fn = self._perm_multiclass_fn(num_classes)
-            identity = jnp.arange(y.shape[0], dtype=jnp.int32)[None]
-            padded = self._pad_rows(identity, owned=True)[0]
+            padded = self._pad_rows(y[None], owned=True)[0]
             self._count_step2(plan, padded.shape[0], num_classes)
-            return self.tracer.sync(fn(plan, y, padded)[0])
+            return self.tracer.sync(fn(plan, padded)[0])
 
     def null_multiclass(
-        self, plan: fastcv.CVPlan, y: jax.Array, perms: jax.Array, *, num_classes: int
+        self, plan: fastcv.CVPlan, y_rows: jax.Array, *, num_classes: int,
+        requested: Optional[int] = None,
     ) -> jax.Array:
-        """Multi-class analogue of :meth:`null_binary` → (B,) accuracies."""
+        """Multi-class analogue of :meth:`null_binary`: (B, N) permuted
+        label rows → (B,) accuracies, on the local path."""
         with self.tracer.span("null_chunk"):
             fn = self._perm_multiclass_fn(num_classes)
-            padded, b = self._pad_rows(perms, owned=True)
+            padded, b = self._pad_rows(y_rows, owned=True)
             self._count_step2(plan, padded.shape[0], num_classes)
-            out = self.tracer.sync(fn(plan, y, padded)[:b])
-        with self._lock:
-            self.labels_evaluated += b
+            out = self.tracer.sync(fn(plan, padded)[:b])
+        self._count_null("local", b if requested is None else requested, padded.shape[0])
         return out
 
     def permutation_binary(
@@ -1212,10 +1211,9 @@ class CVEngine:
         eval path. Either way the draws are generated at the bucket size,
         so repeats never recompile and both paths evaluate the same draws.
         """
-        n = y.shape[0]
         observed = self.observed_binary(plan, y, metric=metric, adjust_bias=adjust_bias)
-        # Generate directly at the bucket size: permutation_indices jits on
-        # static (n, T), so bucketing T here is what keeps arbitrary
+        # Generate directly at the bucket size: permutation_draws jits on
+        # static T, so bucketing T here is what keeps arbitrary
         # client-chosen n_perm from compiling a fresh generator each time.
         # Draw generation and the p-value are null-distribution work, so
         # they count toward the null_chunk stage (timings() sums same-name
@@ -1223,13 +1221,16 @@ class CVEngine:
         # stage-sum ≈ end-to-end acceptance invariant.
         t_gen = bucket_size(n_perm, self.config.buckets)
         with self.tracer.span("null_chunk"):
+            # the labels the null reads, shuffled: cast before the draws
+            y = y.astype(plan.h.dtype)
             if self.config.mesh is not None:
                 # every chip draws them all and keeps its shard: no transfer
-                key = jax.device_put(key, NamedSharding(self.config.mesh, PartitionSpec()))
-            perms = self.tracer.sync(perm_lib.permutation_indices(key, n, t_gen))
+                key, y = jax.device_put(
+                    (key, y), NamedSharding(self.config.mesh, PartitionSpec()))
+            y_perm = self.tracer.sync(perm_lib.permutation_draws(key, y, t_gen))
         # this API's contract (and the multiclass path) counts the
         # *requested* draws only
-        null = self.null_binary(plan, y, perms, metric=metric, adjust_bias=adjust_bias,
+        null = self.null_binary(plan, y_perm, metric=metric, adjust_bias=adjust_bias,
                                 requested=n_perm)[:n_perm]
         with self.tracer.span("null_chunk"):
             p = self.tracer.sync(perm_lib.p_value(observed, null))
@@ -1245,17 +1246,12 @@ class CVEngine:
         num_classes: int,
     ) -> perm_lib.PermutationResult:
         """Algorithm 2 under permutations against a cached plan."""
-        fn = self._perm_multiclass_fn(num_classes)
-        n = y.shape[0]
         observed = self.observed_multiclass(plan, y, num_classes=num_classes)
         t_gen = bucket_size(n_perm, self.config.buckets)
         with self.tracer.span("null_chunk"):
-            perms = self.tracer.sync(perm_lib.permutation_indices(key, n, t_gen))
-            padded = self._pad_rows(perms, owned=True)[0]
-            self._count_step2(plan, padded.shape[0], num_classes)
-            null = self.tracer.sync(fn(plan, y, padded)[:n_perm])
-        with self._lock:
-            self.labels_evaluated += n_perm
+            y_rows = self.tracer.sync(perm_lib.permutation_draws(key, y, t_gen))
+        null = self.null_multiclass(plan, y_rows, num_classes=num_classes,
+                                    requested=n_perm)[:n_perm]
         with self.tracer.span("null_chunk"):
             p = self.tracer.sync(perm_lib.p_value(observed, null))
         return perm_lib.PermutationResult(observed, null, p)

@@ -104,12 +104,12 @@ METRICS = {
     "null_draws_total": {
         "kind": "counter",
         "labels": ("path",),
-        "help": "Binary permutation-null draws requested, by path (mesh or local)",
+        "help": "CV permutation-null draws requested (binary and multi-class), by path (mesh or local)",
     },
     "null_pad_draws_total": {
         "kind": "counter",
         "labels": ("path",),
-        "help": "Binary permutation-null rows evaluated as padding (to the bucket and to whole shards), by path",
+        "help": "CV permutation-null rows evaluated as padding (to the bucket and to whole shards), by path",
     },
     "stage_latency_seconds": {
         "kind": "histogram",

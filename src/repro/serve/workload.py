@@ -1232,14 +1232,16 @@ def _chunk_plan(engine, total: int, chunk: int) -> tuple[int, int]:
     buckets = engine.config.buckets
     t_gen = bucket_size(total, buckets)
     chunk = min(bucket_size(chunk, buckets), t_gen)
-    # whole chunks, same prefix (permutation_indices is prefix-stable)
+    # whole chunks, same prefix (permutation_draws is prefix-stable)
     return -(-t_gen // chunk) * chunk, chunk
 
 
-def _null_chunks(engine, total: int, n_items: int, seed: int, chunk: int, eval_chunk):
+def _null_chunks(engine, total: int, payload, seed: int, chunk: int, eval_chunk):
     """Shared streaming loop: yield (done, null_block) chunk by chunk.
 
-    Permutations of ``n_items`` are generated once at the bucketed total —
+    Permutations of ``payload`` — what the evaluation reads: the labels of
+    a CV null, ``arange(C)`` for an RSA null's condition order — are
+    generated once at the bucketed total —
     rounded up to a whole number of chunks, so every slice is a full chunk
     with one static shape even under non-nested custom buckets — and
     evaluated ``chunk`` rows at a time; repeats never recompile, and the
@@ -1248,10 +1250,10 @@ def _null_chunks(engine, total: int, n_items: int, seed: int, chunk: int, eval_c
     its own output to ``keep``.
     """
     t_gen, chunk = _chunk_plan(engine, total, chunk)
-    perms = perm_lib.permutation_indices(jax.random.PRNGKey(seed), n_items, t_gen)
+    draws = perm_lib.permutation_draws(jax.random.PRNGKey(seed), payload, t_gen)
     for lo in range(0, total, chunk):
         hi = min(lo + chunk, total)
-        yield hi, eval_chunk(perms[lo : min(lo + chunk, t_gen)], hi - lo)
+        yield hi, eval_chunk(draws[lo : min(lo + chunk, t_gen)], hi - lo)
 
 
 def stream_workload(engine, workload, chunk: int = 64) -> Iterator[ProgressEvent]:
@@ -1335,26 +1337,28 @@ def _stream_permutation(engine, w: Workload, chunk: int, tracer=NULL_TRACER, tr=
     if w.estimator == "multiclass":
         with tracer.activate(tr):
             observed = engine.observed_multiclass(plan, y, num_classes=w.num_classes)
+        payload = y
 
         def eval_chunk(block, keep):
             with tracer.activate(tr):
-                return engine.null_multiclass(plan, y, block, num_classes=w.num_classes)[:keep]
+                return engine.null_multiclass(plan, block, num_classes=w.num_classes)[:keep]
 
     else:
         with tracer.activate(tr):
             observed = engine.observed_binary(
                 plan, y, metric=w.metric, adjust_bias=w.adjust_bias
             )
+        payload = y.astype(plan.h.dtype)  # what the null reads, as permutation_binary
 
         def eval_chunk(block, keep):
             with tracer.activate(tr):
                 return engine.null_binary(
-                    plan, y, block, metric=w.metric, adjust_bias=w.adjust_bias
+                    plan, block, metric=w.metric, adjust_bias=w.adjust_bias
                 )[:keep]
 
     yield ProgressEvent("observed", 0, total, observed)
     chunks = []
-    for hi, null_block in _null_chunks(engine, total, int(y.shape[0]), w.seed, chunk, eval_chunk):
+    for hi, null_block in _null_chunks(engine, total, payload, w.seed, chunk, eval_chunk):
         chunks.append(null_block)
         yield ProgressEvent("null", hi, total, null_block)
 
@@ -1489,7 +1493,7 @@ def _stream_rsa(engine, w: Workload, chunk: int, tracer=NULL_TRACER, tr=None):
             return engine.null_rdm_scores(rdm, models, block, w.comparison)[:, :keep]
 
     chunks = []
-    for hi, null_block in _null_chunks(engine, total, c, w.seed, chunk, eval_chunk):
+    for hi, null_block in _null_chunks(engine, total, np.arange(c), w.seed, chunk, eval_chunk):
         chunks.append(null_block)
         yield ProgressEvent("null", hi, total, null_block)
 

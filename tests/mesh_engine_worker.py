@@ -92,8 +92,8 @@ def main():
 
     # a chunk that is no whole number of shards: padded, then cut back
     _, plan = mesh_engine.resolve(h_mesh)
-    chunk = permutation_indices(SEED, N, 30)
-    part = mesh_engine.null_binary(plan, jax.numpy.asarray(y), chunk)
+    chunk = y[permutation_indices(SEED, N, 30)]
+    part = mesh_engine.null_binary(plan, jax.numpy.asarray(chunk))
     out["chunk"] = {"size": int(part.shape[0]),
                     "equal": bool(np.array_equal(np.asarray(part), np.asarray(r_mesh.null)[:30]))}
     draws_c = mesh_engine.metrics.get("null_draws_total")

@@ -19,8 +19,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core import fastcv, multiclass
 from repro.kernels.fold_eval import ops as fold_eval_ops
@@ -34,7 +35,8 @@ F32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described TPU v5e 2×2 host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -48,10 +50,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo.devices
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was_on)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture
@@ -137,10 +144,41 @@ def test_multiclass_null_eval_has_no_eigh_for_v5e(one_chip):
     from repro.serve import CVEngine
 
     fn = CVEngine()._perm_multiclass_fn(3)
-    text = _compile(
-        fn,
-        _plan_spec(one_chip, True),
-        _spec((N,), jnp.int32, one_chip),
-        _spec((1024, N), jnp.int32, one_chip),
-    )
+    text = _compile(fn, _plan_spec(one_chip, True), _spec((1024, N), jnp.int32, one_chip))
     assert "Eigh" not in text
+
+
+def _scalar_gathers(text: str) -> list:
+    """The compiled program's gathers that read one element per index."""
+    return [line for line in text.splitlines()
+            if " gather(" in line and "slice_sizes={1}" in line]
+
+
+@pytest.mark.parametrize("estimator", ["binary", "multiclass"])
+def test_null_eval_gathers_no_scalars_for_v5e(one_chip, estimator):
+    """The engine's null programs (``jit__eval``) read 1024 draws of
+    permuted labels as rows: no gather of one label at a time is left."""
+    from repro.serve import CVEngine
+
+    if estimator == "binary":
+        fn, y = CVEngine()._perm_binary_fn("accuracy", True), _spec((1024, N), F32, one_chip)
+    else:
+        fn, y = CVEngine()._perm_multiclass_fn(3), _spec((1024, N), jnp.int32, one_chip)
+    text = _compile(fn, _plan_spec(one_chip, True), y)
+    assert " gather(" in text  # the row gathers of the fold split remain
+    assert _scalar_gathers(text) == []
+
+
+def test_mesh_null_gathers_no_scalars_for_v5e(v5e_2x2):
+    """The mesh null (``jit__mesh_null``) at 10,240 draws over a 2 × 2 mesh,
+    as the four-chip cell runs it: each chip reads its rows of permuted
+    labels, with no gather of one label at a time."""
+    from repro.core.distributed import mesh_null_program
+
+    mesh = Mesh(np.asarray(v5e_2x2).reshape(2, 2), ("data", "model"))
+    everywhere = NamedSharding(mesh, PartitionSpec())
+    fn = mesh_null_program(mesh, perm_axes=("data", "model"))
+    with jax.set_mesh(mesh):
+        text = _compile(fn, _plan_spec(everywhere, True), _spec((10240, N), F32, everywhere))
+    assert "all-gather" in text
+    assert _scalar_gathers(text) == []

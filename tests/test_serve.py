@@ -90,6 +90,21 @@ def test_engine_permutation_matches_library(problem, engine):
     assert abs(float(res_e.p) - float(res_l.p)) < 1e-12
 
 
+@pytest.mark.parametrize("estimator", ["binary", "multiclass"])
+def test_null_draw_counters_count_every_cv_null(problem, engine, estimator):
+    """Both CV nulls count the draws asked for and the bucket's padding."""
+    x, y, yc, f = problem
+    _, plan = engine.plan(x, f, LAM)
+    key = jax.random.PRNGKey(3)
+    if estimator == "binary":
+        engine.permutation_binary(plan, y, 20, key)
+    else:
+        engine.permutation_multiclass(plan, yc, 20, key, num_classes=3)
+    draws = engine.metrics.get("null_draws_total").value(path="local")
+    pad = engine.metrics.get("null_pad_draws_total").value(path="local")
+    assert (draws, pad) == (20, bucket_size(20, engine.config.buckets) - 20)
+
+
 def test_engine_gram_impl_pallas_matches_xla(problem):
     x, y, _, f = problem
     e_xla = CVEngine(EngineConfig(gram_impl="xla"))
