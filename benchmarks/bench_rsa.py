@@ -19,7 +19,7 @@ from benchmarks.common import row, timeit
 from repro import rsa
 from repro.core import folds as foldlib
 from repro.data import synthetic
-from repro.serve import CVEngine, DatasetSpec, Workload, serve
+from repro.serve import CVEngine, DatasetSpec, Workload, run_workloads
 
 
 def run(fast: bool = False):
@@ -42,7 +42,7 @@ def run(fast: bool = False):
     # -- cold: fresh engine; plan build + compile + eval -------------------
     engine = CVEngine()
     t0 = time.perf_counter()
-    jax.block_until_ready(serve(engine, [req])[0].rdm)
+    jax.block_until_ready(run_workloads(engine, [req])[0].rdm)
     t_cold = time.perf_counter() - t0
     rows.append(
         row(f"bench_rsa_cold_N{n}_P{p}_C{c}", t_cold, "plan build + compile + RDM + model scoring")
@@ -52,7 +52,7 @@ def run(fast: bool = False):
     compiles_warm = engine.compile_count()
 
     def warm_once():
-        return serve(engine, [req])[0].rdm
+        return run_workloads(engine, [req])[0].rdm
 
     t_warm = timeit(warm_once, warmup=1, repeats=5)
     recompiles = engine.compile_count() - compiles_warm
@@ -80,7 +80,7 @@ def run(fast: bool = False):
         ]
 
         def rsa_batch():
-            return [r.rdm for r in serve(engine, reqs)]
+            return [r.rdm for r in run_workloads(engine, reqs)]
 
         secs = timeit(rsa_batch, warmup=1, repeats=5)
         rows.append(row(f"bench_rsa_warm_batch{bs}_N{n}_P{p}_C{c}", secs, f"{bs / secs:.0f} req/s"))

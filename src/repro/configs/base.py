@@ -142,7 +142,7 @@ for _n, _m in {
     "xlstm-125m": "repro.configs.xlstm_125m",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
     "qwen3-moe-30b-a3b": "repro.configs.qwen3_moe_30b_a3b",
-    # the paper's own workload has no transformer; see repro.launch.probe
+    # the paper's own workload has no transformer; these serve the LM substrate only
 }.items():
     register(_n, _m)
 

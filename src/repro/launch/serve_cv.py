@@ -1,7 +1,7 @@
 """Stand up the analytical-CV serving engine and measure throughput.
 
     PYTHONPATH=src python -m repro.launch.serve_cv --requests 64
-    PYTHONPATH=src python -m repro.launch.serve_cv --data eeg --clients 4
+    PYTHONPATH=src python -m repro.launch.serve_cv --data eeg --async 4
     PYTHONPATH=src python -m repro.launch.serve_cv --rsa --conditions 8
     PYTHONPATH=src python -m repro.launch.serve_cv --warmup --pin --async 8
     PYTHONPATH=src python -m repro.launch.serve_cv --record-traffic t.json
@@ -19,10 +19,9 @@ built, evals compiled), then warm (everything cached). With ``--rsa``
 the stream becomes RSA traffic instead: cross-validated RDMs
 (pairwise-contrast and confusion), scored against model RDMs with
 condition-permutation nulls, all riding the same cached plans and
-coalesced label batches. With ``--clients > 1`` the same stream is
-replayed through a thread-transport Client so concurrent submitters
-coalesce onto shared micro-batches; with ``--async N`` through an
-async-transport Client (N coroutine clients), followed by a streamed
+coalesced label batches. With ``--async N`` the same stream is replayed
+through an async-transport Client (N coroutine clients, whose concurrent
+submissions coalesce onto shared micro-batches), followed by a streamed
 permutation workload printing its null chunks as they land. ``--warmup``
 pre-builds every plan and pre-compiles the bucketed eval family before
 the first timed pass (``--pin`` additionally pins the warmed plans
@@ -381,8 +380,6 @@ def main():
     ap.add_argument("--lam", type=float, default=1.0)
     ap.add_argument("--perm", type=int, default=64,
                     help="permutations per permutation workload")
-    ap.add_argument("--clients", type=int, default=0,
-                    help="if > 1, replay warm through this many threads")
     ap.add_argument("--async", type=int, default=0, dest="async_clients",
                     metavar="N", help="if > 1, replay warm through the "
                     "asyncio transport with N coroutine clients + stream demo")
@@ -518,32 +515,6 @@ def main():
           f"   warm: {t_warm:.3f}s ({len(workloads)/t_warm:.1f} req/s)"
           f"   speedup {t_cold/t_warm:.1f}x, "
           f"recompiles on warm replay: {warm_recompiles}")
-
-    if args.clients > 1:
-        import threading
-        per_client = -(-len(workloads) // args.clients)
-        with Client(engine, transport="thread", max_batch=per_client) as tclient:
-            results = [None] * len(workloads)
-
-            def one_client(cid):
-                lo = cid * per_client
-                futs = [(j, tclient.submit(workloads[j]))
-                        for j in range(lo, min(lo + per_client, len(workloads)))]
-                for j, f in futs:
-                    results[j] = f.result(timeout=600)
-
-            t0 = time.perf_counter()
-            threads = [threading.Thread(target=one_client, args=(c,))
-                       for c in range(args.clients)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            t_threaded = time.perf_counter() - t0
-            print(f"[serve_cv] threaded ({args.clients} clients): "
-                  f"{t_threaded:.3f}s ({len(workloads)/t_threaded:.1f} req/s) "
-                  f"in {tclient.server.batches_served} micro-batches")
-        assert all(r is not None for r in results)
 
     if args.async_clients > 1:
         demo = None

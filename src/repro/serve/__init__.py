@@ -13,7 +13,7 @@ productises that behind a single declarative surface:
             registrations, not engine forks; run_workloads /
             stream_workload drivers; TrafficLog.
   client    Client — submit/stream/gather over a transport chosen by
-            construction (sync, thread-queue, or asyncio).
+            construction (sync or asyncio).
   cache     PlanCache — LRU CVPlan store under a byte budget, with
             admission control for plans larger than the whole budget and
             pin/unpin for warm, never-evicted plans.
@@ -29,8 +29,6 @@ productises that behind a single declarative surface:
             memoisation, and an explicit warmup() readiness API
             (replayable from recorded traffic).
   batching  MicroBatcher — coalesce ragged same-plan label queries.
-  api       Sync driver + threaded queue server (the pre-0.1 request
-            shims were removed at 0.3; see the README migration table).
   aio       AsyncEngineServer — asyncio front-end with gather-window
             micro-batching and streamed permutation/RSA responses.
   http      HTTPEdge — the HTTP/SSE wire over the async server (Workload
@@ -52,16 +50,6 @@ network edge).
 """
 
 from repro.serve.aio import AsyncEngineServer, ProgressEvent  # noqa: F401
-from repro.serve.api import (  # noqa: F401
-    CVResponse,
-    DatasetSpec,
-    EngineServer,
-    GridResponse,
-    PermutationResponse,
-    RSAResponse,
-    TuneResponse,
-    serve,
-)
 from repro.serve.batching import MicroBatcher, bucket_size  # noqa: F401
 from repro.serve.cache import CacheStats, PlanCache  # noqa: F401
 from repro.serve.client import Client  # noqa: F401
@@ -77,9 +65,15 @@ from repro.serve.store import PlanStore, StoreStats  # noqa: F401
 from repro.serve.trace import STAGES, Span, Trace, Tracer  # noqa: F401
 from repro.serve.workload import (  # noqa: F401
     WORKLOAD_SCHEMA_VERSION,
+    CVResponse,
     DatasetHandle,
+    DatasetSpec,
+    GridResponse,
     LeastSquaresSpec,
+    PermutationResponse,
+    RSAResponse,
     TrafficLog,
+    TuneResponse,
     UpdateResponse,
     Workload,
     as_workload,

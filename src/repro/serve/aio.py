@@ -1,19 +1,17 @@
 """repro.serve.aio — asyncio front-end over the CV engine.
 
-The sync drivers in :mod:`repro.serve.api` make one of two trades: the
-blocking :func:`~repro.serve.api.serve` wants the whole batch up front,
-and the thread-queue :class:`~repro.serve.api.EngineServer` gives each
-submitter a `concurrent.futures.Future` but keeps long work monolithic —
-one slow permutation workload head-of-line-blocks every cheap binary
-query behind it. This module turns the engine into a traffic-shaped
-service:
+The sync driver :func:`~repro.serve.workload.run_workloads` wants the
+whole batch up front and keeps long work monolithic — one slow
+permutation workload head-of-line-blocks every cheap binary query behind
+it. This module turns the engine into a traffic-shaped service, and is
+the one concurrent server in the package (the HTTP edge mounts on it):
 
 * :class:`AsyncEngineServer` — submitters ``await server.submit(w)``
-  (a :class:`~repro.serve.workload.Workload` or a legacy request shim)
-  from any coroutine; the worker gathers whatever arrives inside a
-  deadline-bounded window (``gather_window_ms`` after the first request,
-  up to ``max_batch``) and serves the whole group through the sync
-  driver, so same-plan traffic still coalesces through the engine's
+  (a :class:`~repro.serve.workload.Workload`) from any coroutine; the
+  worker gathers whatever arrives inside a deadline-bounded window
+  (``gather_window_ms`` after the first request, up to ``max_batch``)
+  and serves the whole group through the sync driver, so same-plan
+  traffic still coalesces through the engine's
   :class:`~repro.serve.batching.MicroBatcher` into one padded jitted
   eval per flush group. Engine compute runs on a single executor thread;
   the event loop never blocks on XLA.

@@ -1,24 +1,24 @@
-"""repro.serve.client — one Client, three transports.
+"""repro.serve.client — one Client, two transports.
 
 Every driver in this package executes the same
 :class:`~repro.serve.workload.Workload` spec through the same engine; the
 only real choice a caller makes is *how submissions travel*: inline on
-the calling thread, through the thread-backed queue, or through the
-asyncio gather window. :class:`Client` makes that a constructor argument
-instead of three APIs:
+the calling thread, or through the asyncio gather window.
+:class:`Client` makes that a constructor argument instead of two APIs:
 
     client = Client(engine)                      # sync, in-process
-    client = Client(engine, transport="thread")  # EngineServer futures
     client = Client(engine, transport="async")   # AsyncEngineServer
 
 ``submit`` / ``gather`` / ``stream`` then have transport-appropriate
-return types (response vs Future vs awaitable; generator vs async
-generator) but identical semantics and — by the parity tests —
-bit-identical results. The client also fronts the engine's dataset
-registry (``register`` → :class:`~repro.serve.workload.DatasetHandle`)
-and, given a :class:`~repro.serve.workload.TrafficLog`, records the
-(task, bucket) set of everything submitted so a later boot can warm the
-engine from observed traffic (``serve_cv --record-traffic`` /
+return types (response vs awaitable; generator vs async generator) but
+identical semantics and — by the parity tests — bit-identical results.
+A sync client may be shared across user threads: the engine serialises
+its counters under its own lock. The client also fronts the engine's
+dataset registry (``register`` →
+:class:`~repro.serve.workload.DatasetHandle`) and, given a
+:class:`~repro.serve.workload.TrafficLog`, records the (task, bucket)
+set of everything submitted so a later boot can warm the engine from
+observed traffic (``serve_cv --record-traffic`` /
 ``--warmup-from``).
 """
 
@@ -28,7 +28,6 @@ import asyncio
 from typing import Optional, Sequence
 
 from repro.serve.aio import AsyncEngineServer
-from repro.serve.api import EngineServer
 from repro.serve.engine import CVEngine
 from repro.serve.workload import (
     DatasetHandle,
@@ -40,7 +39,7 @@ from repro.serve.workload import (
 
 __all__ = ["Client"]
 
-_TRANSPORTS = ("sync", "thread", "async")
+_TRANSPORTS = ("sync", "async")
 
 
 class Client:
@@ -49,12 +48,6 @@ class Client:
     transport="sync"    ``submit`` returns the response, ``gather`` the
                         response list (whole batch coalesced through one
                         driver call), ``stream`` a plain generator.
-    transport="thread"  ``submit`` returns a ``concurrent.futures.Future``
-                        from a lazily-started
-                        :class:`~repro.serve.api.EngineServer`; ``gather``
-                        blocks for all results; ``stream`` runs on the
-                        calling thread (the engine is thread-safe, so
-                        chunks interleave with the worker's batches).
     transport="async"   use ``async with Client(...)``; ``submit`` /
                         ``gather`` are awaitables and ``stream`` an async
                         iterator over an
@@ -79,7 +72,7 @@ class Client:
         self.gather_window_ms = gather_window_ms
         self.stream_chunk = stream_chunk
         self.record = record
-        self._server = None  # EngineServer | AsyncEngineServer | None
+        self._server: Optional[AsyncEngineServer] = None
 
     # -- dataset registry passthrough --------------------------------------
 
@@ -111,22 +104,20 @@ class Client:
 
     def submit(self, workload):
         """One workload in; transport-appropriate handle out
-        (response / Future / awaitable)."""
+        (response / awaitable)."""
         w = as_workload(workload)
         self._note(w)
         if self.transport == "sync":
             (resp,) = run_workloads(self.engine, [w])
             return resp
-        if self.transport == "thread":
-            return self._thread_server().submit(w)
         return self._async_server().submit(w)
 
     def gather(self, workloads: Sequence, *, return_errors: bool = False):
         """Submit a batch; return (or await) the aligned response list.
 
         The sync transport coalesces the whole batch through one driver
-        call (maximal micro-batching); thread/async submit individually so
-        the batch interleaves with other clients' traffic.
+        call (maximal micro-batching); async submits individually so the
+        batch interleaves with other clients' traffic.
 
         With ``return_errors=True`` a failing workload yields its
         exception object in the corresponding slot instead of aborting the
@@ -151,15 +142,6 @@ class Client:
             for (i, _), r in zip(live, out):
                 results[i] = r
             return results
-        if self.transport == "thread":
-            futures = [(i, self._thread_server().submit(w)) for i, w in live]
-            for i, f in futures:
-                if return_errors:
-                    e = f.exception()
-                    results[i] = e if e is not None else f.result()
-                else:
-                    results[i] = f.result()
-            return results
 
         async def _gather():
             server = self._async_server()
@@ -173,8 +155,8 @@ class Client:
         return _gather()
 
     def stream(self, workload):
-        """Progress events for one workload: a generator (sync/thread
-        transports) or an async iterator (async transport)."""
+        """Progress events for one workload: a generator (sync transport)
+        or an async iterator (async transport)."""
         w = as_workload(workload)
         self._note(w, stream_chunk=self.stream_chunk)
         if self.transport == "async":
@@ -182,13 +164,6 @@ class Client:
         return stream_workload(self.engine, w, chunk=self.stream_chunk)
 
     # -- lifecycle ---------------------------------------------------------
-
-    def _thread_server(self) -> EngineServer:
-        if self._server is None:
-            self._server = EngineServer(
-                self.engine, max_batch=self.max_batch, max_wait_ms=self.gather_window_ms
-            ).start()
-        return self._server
 
     def _async_server(self) -> AsyncEngineServer:
         if self._server is None:
@@ -198,18 +173,13 @@ class Client:
             )
         return self._server
 
-    def close(self) -> None:
-        if self.transport == "thread" and self._server is not None:
-            self._server.stop()
-            self._server = None
-
     def __enter__(self) -> "Client":
         if self.transport == "async":
             raise RuntimeError("async Client needs `async with`, not `with`")
         return self
 
     def __exit__(self, *exc) -> None:
-        self.close()
+        pass  # the sync transport holds nothing to release
 
     async def __aenter__(self) -> "Client":
         if self.transport != "async":

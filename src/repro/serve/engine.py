@@ -197,10 +197,10 @@ class EngineConfig:
 class CVEngine:
     """Multi-tenant analytical-CV evaluation engine."""
 
-    # Concurrency contract, machine-checked by reprolint RL004: the
-    # thread server (EngineServer) and the asyncio gather loop both drive
-    # one engine, so the lifetime stat counters increment under _lock —
-    # a lost `+= b` here silently skews capacity accounting.
+    # Concurrency contract, machine-checked by reprolint RL004: user
+    # threads sharing a sync Client and the asyncio gather loop's engine
+    # thread all drive one engine, so the lifetime stat counters increment
+    # under _lock — a lost `+= b` here silently skews capacity accounting.
     _GUARDED_BY = {
         "plans_built": "_lock",
         "plans_updated": "_lock",
@@ -777,7 +777,7 @@ class CVEngine:
         """Pre-build the plan for ``spec`` and pre-compile eval programs.
 
         ``spec`` is anything with ``x`` / ``folds`` / ``lam`` (and
-        optionally ``mode``) attributes — e.g. :class:`repro.serve.api
+        optionally ``mode``) attributes — e.g. :class:`repro.serve.workload
         .DatasetSpec`. ``tasks`` selects eval families from
         {"binary", "ridge", "multiclass", "permutation", "rsa"};
         ``buckets`` the label-batch sizes to compile (default: every

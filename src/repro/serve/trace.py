@@ -91,7 +91,7 @@ STAGES = (
     "plan_update",  # rank-k append/retire/window correction (kind="update")
     "cache_lookup",  # plan_key fingerprint + cache probe
     "store_load",  # disk plan-store read + integrity check (miss path)
-    "batch_wait",  # submit -> dequeue latency (thread/async servers)
+    "batch_wait",  # submit -> dequeue latency (the async server's gather window)
     "executor_wait",  # submit -> start of a job on the async server's engine thread
     "eval",  # bucketed jitted eval (scores, RDMs, tune sweeps)
     "null_chunk",  # permutation-null chunks (monolithic or streamed)
@@ -219,7 +219,7 @@ class Trace:
         return twin
 
     def mark_enqueue(self) -> None:
-        """Submit side of the batch_wait stage (thread/async servers)."""
+        """Submit side of the batch_wait stage (the async server)."""
         self._t_enqueue = time.perf_counter()
 
     def note_dequeue(self, now: Optional[float] = None) -> None:

@@ -865,7 +865,9 @@ def _rdm_memo_key(plan_key, w: Workload):
 def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -> list:
     """Serve a batch of workloads; responses align with ``workloads``.
 
-    Same-plan CV label queries coalesce into one padded jitted eval per
+    This is the one synchronous driver: :class:`~repro.serve.client.Client`
+    with the sync transport calls it inline, and the async server calls it
+    once per gather window. Same-plan CV label queries coalesce into one padded jitted eval per
     (plan, estimator, static-options) group; RSA contrast columns ride the
     same column path with empirical-RDM memoisation (repeat scoring of the
     same (plan, labels) skips the fold solves entirely); permutation, tune,
@@ -880,9 +882,8 @@ def run_workloads(engine, workloads: Sequence, *, return_errors: bool = False) -
     object* in the corresponding slot instead of aborting the batch, so
     sibling workloads — including other clients' traffic coalesced into
     the same gather window — still get served. The batch transports
-    (:class:`~repro.serve.api.EngineServer`,
-    :class:`~repro.serve.aio.AsyncEngineServer`, and the HTTP edge) run in
-    this mode and fan each entry's result-or-error back to its own
+    (:class:`~repro.serve.aio.AsyncEngineServer` and the HTTP edge over it)
+    run in this mode and fan each entry's result-or-error back to its own
     submitter.
 
     Observability: when the engine's tracer is enabled, every workload

@@ -24,7 +24,7 @@ from repro.serve import (
     EngineConfig,
     ProgressEvent,
     Workload,
-    serve,
+    run_workloads,
 )
 
 N, P, K, LAM = 48, 96, 4, 1.0
@@ -67,7 +67,7 @@ def _mixed_requests(problem, n_perm=12):
 
 def test_async_server_matches_sync(problem):
     requests = _mixed_requests(problem)
-    sync = serve(CVEngine(), requests)
+    sync = run_workloads(CVEngine(), requests)
     engine = CVEngine()
 
     async def main():
@@ -283,7 +283,7 @@ def test_stream_rsa_events(problem):
     assert kinds[0] == "plan" and kinds[1] == "rdm" and kinds[2] == "scores"
     assert kinds[-1] == "done"
     final = events[-1].payload
-    (sync,) = serve(CVEngine(), [req])
+    (sync,) = run_workloads(CVEngine(), [req])
     np.testing.assert_allclose(np.asarray(final.rdm), np.asarray(sync.rdm), rtol=1e-9, atol=1e-12)
     np.testing.assert_allclose(
         np.asarray(final.model_scores), np.asarray(sync.model_scores), rtol=1e-9, atol=1e-12

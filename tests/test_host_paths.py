@@ -22,7 +22,7 @@ from repro.serve import (
     DatasetSpec,
     EngineConfig,
     Workload,
-    serve,
+    run_workloads,
     stream_workload,
 )
 
@@ -51,23 +51,23 @@ def test_stat_counters_exact_under_concurrent_submissions():
     serial = CVEngine()
     h = serial.register(x, f, LAM)
     w = Workload(kind="cv", dataset=h, y=y)
-    serve(serial, [w])  # absorb plan build + first-shape compiles
+    run_workloads(serial, [w])  # absorb plan build + first-shape compiles
     before = serial.stats()["labels_evaluated"]
-    serve(serial, [w])
+    run_workloads(serial, [w])
     per_call = serial.stats()["labels_evaluated"] - before
     assert per_call > 0
 
     engine = CVEngine()
     handle = engine.register(x, f, LAM)
     wt = Workload(kind="cv", dataset=handle, y=y)
-    serve(engine, [wt])  # warm the plan so threads only contend on evals
+    run_workloads(engine, [wt])  # warm the plan so threads only contend on evals
     start = engine.stats()["labels_evaluated"]
     barrier = threading.Barrier(workers)
 
     def drive():
         barrier.wait()
         for _ in range(per_worker):
-            serve(engine, [wt])
+            run_workloads(engine, [wt])
 
     threads = [threading.Thread(target=drive) for _ in range(workers)]
     for t in threads:
@@ -126,7 +126,7 @@ def test_streamed_rsa_null_and_p_match_batch_exactly():
     final = events[-1].payload
     assert isinstance(final.null, np.ndarray)
 
-    (batch,) = serve(CVEngine(), [w])
+    (batch,) = run_workloads(CVEngine(), [w])
     np.testing.assert_array_equal(final.null, np.asarray(batch.null))
     np.testing.assert_array_equal(np.asarray(final.p), np.asarray(batch.p))
     np.testing.assert_array_equal(
@@ -147,7 +147,7 @@ def test_update_batch_coalescing_matches_single_concatenated_update():
 
     coalesced = CVEngine(EngineConfig(cache_bytes=64 << 20))
     h0 = coalesced.register(x, f, LAM)
-    r1, r2 = serve(
+    r1, r2 = run_workloads(
         coalesced,
         [
             Workload(kind="update", dataset=h0, x=x1),

@@ -438,7 +438,8 @@ def _bad_handle():
     return DatasetHandle(key=("bogus", "te", "tr", 1.0, "dual", True), n=N, p=P, lam=LAM)
 
 
-def test_gather_surfaces_per_entry_errors_in_process(problem):
+@pytest.mark.parametrize("transport", ["sync", "async"])
+def test_gather_surfaces_per_entry_errors_in_process(problem, transport):
     x, y, yc, f = problem
     engine = CVEngine()
     client = Client(engine)
@@ -448,23 +449,19 @@ def test_gather_surfaces_per_entry_errors_in_process(problem):
     good2 = Workload(kind="cv", dataset=handle, y=yc, estimator="multiclass", num_classes=3)
     ref1, ref2 = client.submit(good1), client.submit(good2)
 
-    for transport in ("sync", "thread", "async"):
-        if transport == "async":
-            import asyncio
+    if transport == "async":
+        import asyncio
 
-            async def drive():
-                async with Client(engine, transport="async") as ac:
-                    return await ac.gather([good1, bad, good2], return_errors=True)
+        async def drive():
+            async with Client(engine, transport="async") as ac:
+                return await ac.gather([good1, bad, good2], return_errors=True)
 
-            out = asyncio.run(drive())
-        elif transport == "thread":
-            with Client(engine, transport="thread") as tc:
-                out = tc.gather([good1, bad, good2], return_errors=True)
-        else:
-            out = client.gather([good1, bad, good2], return_errors=True)
-        assert isinstance(out[1], KeyError), transport
-        _assert_responses_equal(out[0], ref1)
-        _assert_responses_equal(out[2], ref2)
+        out = asyncio.run(drive())
+    else:
+        out = client.gather([good1, bad, good2], return_errors=True)
+    assert isinstance(out[1], KeyError)
+    _assert_responses_equal(out[0], ref1)
+    _assert_responses_equal(out[2], ref2)
 
     # default semantics unchanged: raise on the first failure
     with pytest.raises(KeyError, match="not registered"):

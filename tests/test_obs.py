@@ -315,14 +315,18 @@ def test_enabled_tracing_all_kinds_sync(problem, engine):
     assert h.snapshot(stage="encode")["count"] >= len(ws)
 
 
-def test_thread_transport_batch_wait_and_stage_sum(problem, engine):
+def test_async_transport_batch_wait_and_stage_sum(problem, engine):
     x, y, _, f = problem
     handle = engine.register(x, f, LAM)
     w = Workload(kind="cv", dataset=handle, y=y, estimator="binary")
-    with Client(engine, transport="thread") as client:
-        client.submit(w).result(timeout=300)  # warm
-        engine.enable_tracing()
-        resp = client.submit(w).result(timeout=300)
+
+    async def go():
+        async with Client(engine, transport="async") as client:
+            await client.submit(w)  # warm
+            engine.enable_tracing()
+            return await client.submit(w)
+
+    resp = asyncio.run(go())
     assert "batch_wait" in resp.timings
     (trace,) = engine.tracer.last(1)
     stage_sum = sum(trace["timings"].values())

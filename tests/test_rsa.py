@@ -3,6 +3,8 @@ a NumPy reference, comparison statistics against scipy, permutation nulls,
 the pairdist kernel path, searchlight sharding, and the engine's
 no-recompile guarantee for RSA traffic."""
 
+import asyncio
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,8 +13,8 @@ import pytest
 from repro import rsa
 from repro.core import fastcv, folds as foldlib, multiclass, permutation
 from repro.data import synthetic
-from repro.serve import (CVEngine, DatasetSpec, EngineConfig, EngineServer,
-                         Workload, serve)
+from repro.serve import (Client, CVEngine, DatasetSpec, EngineConfig,
+                         Workload, run_workloads)
 
 C, N_PER, P, K, LAM = 5, 12, 150, 4, 1.0
 N = C * N_PER
@@ -99,7 +101,7 @@ def _np_reference_rdm(x, y_cond, folds, lam, dissimilarity="accuracy",
 def test_serve_rdm_matches_numpy_reference(problem):
     x, y, f = problem
     engine = CVEngine()
-    (resp,) = serve(
+    (resp,) = run_workloads(
         engine, [Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C)]
     )
     want = _np_reference_rdm(x, y, f, LAM)
@@ -111,7 +113,7 @@ def test_serve_rdm_matches_numpy_reference(problem):
 def test_serve_contrast_rdm_matches_numpy_reference(problem):
     x, y, f = problem
     engine = CVEngine()
-    (resp,) = serve(engine, [
+    (resp,) = run_workloads(engine, [
         Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C,
                  dissimilarity="contrast", adjust_bias=False)])
     want = _np_reference_rdm(x, y, f, LAM, dissimilarity="contrast",
@@ -123,7 +125,7 @@ def test_serve_rsa_scores_match_scipy(problem, models):
     scipy_stats = pytest.importorskip("scipy.stats")
     x, y, f = problem
     engine = CVEngine()
-    responses = serve(engine, [
+    responses = run_workloads(engine, [
         Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C,
                  model_rdms=models, comparison=method)
         for method in ("spearman", "kendall")])
@@ -139,7 +141,7 @@ def test_serve_rsa_scores_match_scipy(problem, models):
 def test_serve_rsa_multiclass_confusion(problem):
     x, y, f = problem
     engine = CVEngine()
-    (resp,) = serve(engine, [
+    (resp,) = run_workloads(engine, [
         Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C,
                  contrast="multiclass")])
     plan = fastcv.prepare(x, f, LAM, with_train_block=True)
@@ -166,20 +168,20 @@ def test_warm_rsa_batch_zero_recompiles(problem, models):
     batch = [Workload(kind="rsa", dataset=spec, y=y, num_classes=C,
                       model_rdms=models, n_perm=17, seed=s)
              for s in range(3)]
-    serve(engine, batch)
+    run_workloads(engine, batch)
     warm = engine.compile_count()
     # warm replay: same plan, same shape buckets, different seeds
     batch2 = [Workload(kind="rsa", dataset=spec, y=y, num_classes=C,
                        model_rdms=models, n_perm=20, seed=s)
               for s in range(5, 8)]
-    responses = serve(engine, batch2)
+    responses = run_workloads(engine, batch2)
     assert engine.compile_count() == warm
     assert all(r.null.shape == (2, 20) for r in responses)
     # a second dataset with identical shapes also reuses every program
     x2, y2 = synthetic.make_classification(jax.random.PRNGKey(5), N, P,
                                            num_classes=C, class_sep=2.0)
     spec2 = DatasetSpec(x2, f, LAM)
-    serve(engine, [Workload(kind="rsa", dataset=spec2, y=y2, num_classes=C,
+    run_workloads(engine, [Workload(kind="rsa", dataset=spec2, y=y2, num_classes=C,
                             model_rdms=models, n_perm=20, seed=s)
                    for s in range(3)])
     assert engine.compile_count() == warm
@@ -191,7 +193,7 @@ def test_rsa_shares_plan_with_cv_requests(problem):
     spec = DatasetSpec(x, f, LAM)
     engine = CVEngine()
     y_bin = jnp.where(y % 2 == 0, -1.0, 1.0)
-    serve(engine, [Workload(kind="rsa", dataset=spec, y=y, num_classes=C),
+    run_workloads(engine, [Workload(kind="rsa", dataset=spec, y=y, num_classes=C),
                    Workload(kind="cv", dataset=spec, y=y_bin, estimator="binary"),
                    Workload(kind="cv", dataset=spec, y=y, estimator="multiclass", num_classes=C)])
     assert engine.stats()["plans_built"] == 1
@@ -225,7 +227,7 @@ def test_permutation_null_engine_matches_library(problem, models):
     library calls sharing the key — same contract as CV permutations."""
     x, y, f = problem
     engine = CVEngine()
-    (resp,) = serve(engine, [
+    (resp,) = run_workloads(engine, [
         Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C,
                  model_rdms=models, n_perm=20, seed=7)])
     from repro.serve.batching import bucket_size
@@ -237,7 +239,7 @@ def test_permutation_null_engine_matches_library(problem, models):
     assert resp.p.shape == (2,)
     assert np.all((np.asarray(resp.p) > 0.0) & (np.asarray(resp.p) <= 1.0))
     # a self-model must score (near) perfectly and be significant
-    (self_resp,) = serve(engine, [
+    (self_resp,) = run_workloads(engine, [
         Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C,
                  model_rdms=resp.rdm[None], n_perm=63, seed=2)])
     assert float(self_resp.model_scores[0]) > 0.999
@@ -282,7 +284,7 @@ def test_searchlight_rdm_matches_per_problem(problem):
 
 
 # ---------------------------------------------------------------------------
-# Pair-contrast plumbing + threaded server
+# Pair-contrast plumbing + the async server
 # ---------------------------------------------------------------------------
 
 
@@ -304,10 +306,14 @@ def test_rsa_through_engine_server(problem, models):
     requests = [Workload(kind="rsa", dataset=spec, y=y, num_classes=C,
                          model_rdms=models, n_perm=10, seed=s)
                 for s in range(4)]
-    sync = serve(CVEngine(), requests)
-    with EngineServer(CVEngine(), max_batch=4, max_wait_ms=5.0) as server:
-        futures = [server.submit(r) for r in requests]
-        results = [fu.result(timeout=300) for fu in futures]
+    sync = run_workloads(CVEngine(), requests)
+
+    async def main():
+        async with Client(CVEngine(), transport="async", max_batch=4,
+                          gather_window_ms=5.0) as client:
+            return await client.gather(requests)
+
+    results = asyncio.run(main())
     for got, want in zip(results, sync):
         np.testing.assert_allclose(np.asarray(got.rdm), np.asarray(want.rdm),
                                    rtol=1e-9, atol=1e-12)
@@ -321,7 +327,7 @@ def test_oversized_plan_still_serves_rsa(problem):
     the request un-cached without evicting anything."""
     x, y, f = problem
     engine = CVEngine(EngineConfig(cache_bytes=1024))     # tiny budget
-    (resp,) = serve(
+    (resp,) = run_workloads(
         engine, [Workload(kind="rsa", dataset=DatasetSpec(x, f, LAM), y=y, num_classes=C)]
     )
     want = _np_reference_rdm(x, y, f, LAM)

@@ -11,9 +11,8 @@ import pytest
 
 from repro.core import fastcv, folds as foldlib, multiclass, permutation, regression
 from repro.data import synthetic
-from repro.serve import (CVEngine, DatasetSpec, EngineConfig, EngineServer,
-                         MicroBatcher, PlanCache, Workload, bucket_size,
-                         serve)
+from repro.serve import (CVEngine, DatasetSpec, EngineConfig, MicroBatcher,
+                         PlanCache, Workload, bucket_size, run_workloads)
 
 N, P, K, LAM = 48, 96, 4, 1.0
 
@@ -334,7 +333,7 @@ def test_cv_eval_no_recompile_across_batch_sizes(problem, engine):
 
 
 # ---------------------------------------------------------------------------
-# Driver + threaded server
+# Sync driver
 # ---------------------------------------------------------------------------
 
 
@@ -354,7 +353,7 @@ def _requests(problem, n_perm=12):
 def test_serve_driver_mixed_batch(problem):
     x, y, yc, f = problem
     engine = CVEngine()
-    responses = serve(engine, _requests(problem))
+    responses = run_workloads(engine, _requests(problem))
     dv, _ = fastcv.binary_cv(x, y, f, lam=LAM)
     # coalesced into a (N, 2) batch -> numerically equal, not bitwise
     np.testing.assert_allclose(np.asarray(responses[0].values),
@@ -375,45 +374,9 @@ def test_serve_raw_index_folds(problem):
     x, y, _, f = problem
     spec = DatasetSpec(x, (np.asarray(f.te_idx), np.asarray(f.tr_idx)), LAM)
     engine = CVEngine()
-    (resp,) = serve(engine, [Workload(kind="cv", dataset=spec, y=y, estimator="binary")])
+    (resp,) = run_workloads(engine, [Workload(kind="cv", dataset=spec, y=y, estimator="binary")])
     dv, _ = fastcv.binary_cv(x, y, f, lam=LAM)
     assert bool(jnp.all(resp.values == dv))
-
-
-def test_threaded_server_matches_sync(problem):
-    engine = CVEngine()
-    requests = _requests(problem) * 3
-    sync = serve(CVEngine(), requests)
-    with EngineServer(engine, max_batch=8, max_wait_ms=5.0) as server:
-        futures = [server.submit(r) for r in requests]
-        results = [fu.result(timeout=300) for fu in futures]
-    assert server.requests_served == len(requests)
-    for got, want in zip(results, sync):
-        assert type(got) is type(want)
-        # worker micro-batches may split differently than one sync batch,
-        # so padded shapes (and hence last-bit rounding) can differ
-        if hasattr(want, "values"):
-            np.testing.assert_allclose(np.asarray(got.values),
-                                       np.asarray(want.values),
-                                       rtol=1e-9, atol=1e-12)
-        elif hasattr(want, "null"):
-            np.testing.assert_allclose(np.asarray(got.null),
-                                       np.asarray(want.null),
-                                       rtol=1e-9, atol=1e-12)
-
-
-def test_threaded_server_propagates_errors(problem):
-    x, y, _, f = problem
-    engine = CVEngine()
-    # Workload validates estimator names eagerly, so smuggle an invalid one
-    # past construction to exercise the serve-time error path through the
-    # server's futures.
-    bad = Workload(kind="cv", dataset=DatasetSpec(x, f, LAM), y=y)
-    object.__setattr__(bad, "estimator", "nonsense")
-    with EngineServer(engine) as server:
-        fut = server.submit(bad)
-        with pytest.raises(ValueError):
-            fut.result(timeout=300)
 
 
 def test_workload_rejects_unknown_estimator_eagerly(problem):

@@ -1,5 +1,5 @@
 """Tests for the One-API surface: Workload schema + validation, estimator
-registry, dataset handles, core parity across all three transports,
+registry, dataset handles, core parity across the sync and async transports,
 compile-count flatness between spec- and handle-addressed traffic, the
 0.3 removal of the legacy request shims, RDM memoisation, traffic
 record/replay, and mesh-aware streamed nulls."""
@@ -27,7 +27,7 @@ from repro.serve import (
     as_workload,
     estimators,
     register_estimator,
-    serve,
+    run_workloads,
     stream_workload,
 )
 from repro.serve import workload as workload_mod
@@ -79,10 +79,7 @@ def _assert_responses_equal(got, want, exact=True):
 
 
 def test_removed_shims_raise_importerror_with_migration_pointer():
-    for name in ("CVRequest", "PermutationRequest", "RSARequest", "TuneRequest", "Request"):
-        with pytest.raises(ImportError, match="removed at 0.3"):
-            getattr(__import__("repro.serve.api", fromlist=[name]), name)
-    # the package namespace no longer advertises them either
+    # the package namespace does not advertise the removed request classes
     import repro.serve as serve_pkg
     for name in ("CVRequest", "PermutationRequest", "RSARequest", "TuneRequest"):
         with pytest.raises(AttributeError):
@@ -99,31 +96,29 @@ def test_as_workload_rejects_foreign_objects_with_migration_pointer(problem):
         as_workload(FakeLegacyRequest())
 
 
-def test_parity_across_all_three_transports(problem):
-    """Shim and Workload must be bit-identical through sync, thread, and
-    async transports (sequential submission => identical padded shapes)."""
+@pytest.mark.parametrize("addressing", ["handle", "spec"])
+def test_async_transport_matches_sync(problem, addressing):
+    """The same traffic is bit-identical through the sync and async
+    transports (sequential submission => identical padded shapes), whether
+    it names a registered handle or carries an inline DatasetSpec."""
     x, _, _, f = problem
-    handle_results = {}
-    for transport in ("sync", "thread", "async"):
+    results = {}
+    for transport in ("sync", "async"):
         engine = CVEngine()
-        handle = engine.register(x, f, LAM)
-        ws = _equiv_workloads(problem, handle)
+        dataset = engine.register(x, f, LAM) if addressing == "handle" else DatasetSpec(x, f, LAM)
+        ws = _equiv_workloads(problem, dataset)
         if transport == "async":
 
             async def drive(ws=ws, engine=engine):
                 async with Client(engine, transport="async") as client:
                     return [await client.submit(w) for w in ws]
 
-            handle_results[transport] = asyncio.run(drive())
-        elif transport == "thread":
-            with Client(engine, transport="thread") as client:
-                handle_results[transport] = [client.submit(w).result(timeout=300) for w in ws]
+            results[transport] = asyncio.run(drive())
         else:
             client = Client(engine)
-            handle_results[transport] = [client.submit(w) for w in ws]
-    for transport in ("thread", "async"):
-        for got, want in zip(handle_results[transport], handle_results["sync"]):
-            _assert_responses_equal(got, want, exact=True)
+            results[transport] = [client.submit(w) for w in ws]
+    for got, want in zip(results["async"], results["sync"]):
+        _assert_responses_equal(got, want, exact=True)
 
 
 def test_compile_count_flat_across_spec_and_handle_traffic(problem):
@@ -131,11 +126,11 @@ def test_compile_count_flat_across_spec_and_handle_traffic(problem):
     must not retrace anything: one program family, not two."""
     x, _, _, f = problem
     engine = CVEngine()
-    serve(engine, _equiv_workloads(problem, DatasetSpec(x, f, LAM)))
+    run_workloads(engine, _equiv_workloads(problem, DatasetSpec(x, f, LAM)))
     warm = engine.compile_count()
-    serve(engine, _equiv_workloads(problem, DatasetSpec(x, f, LAM)))
+    run_workloads(engine, _equiv_workloads(problem, DatasetSpec(x, f, LAM)))
     handle = engine.register(x, f, LAM)
-    serve(engine, _equiv_workloads(problem, handle))
+    run_workloads(engine, _equiv_workloads(problem, handle))
     assert engine.compile_count() == warm
     assert engine.stats()["plans_built"] == 1
 
@@ -313,8 +308,8 @@ def test_workload_roundtrip_dict(problem):
         d = w.to_dict()
         assert d["schema"] == 2
         back = Workload.from_dict(d)
-        (a,) = serve(CVEngine(), [w])
-        (b,) = serve(CVEngine(), [back])
+        (a,) = run_workloads(CVEngine(), [w])
+        (b,) = run_workloads(CVEngine(), [back])
         _assert_responses_equal(b, a, exact=True)
     with pytest.raises(ValueError, match="schema version"):
         Workload.from_dict({"schema": 99, "kind": "cv"})
@@ -328,8 +323,8 @@ def test_workload_roundtrip_preserves_handles(problem):
     back = Workload.from_dict(w.to_dict())
     assert isinstance(back.dataset, DatasetHandle)
     assert back.dataset.key == handle.key
-    (a,) = serve(engine, [w])
-    (b,) = serve(engine, [back])  # resolves through the same registration
+    (a,) = run_workloads(engine, [w])
+    (b,) = run_workloads(engine, [back])  # resolves through the same registration
     np.testing.assert_array_equal(np.asarray(a.values), np.asarray(b.values))
 
 
@@ -347,7 +342,7 @@ def test_register_is_idempotent_and_introspectable(problem):
     assert h1.n == N and h1.p == P
     (info,) = engine.datasets()
     assert info["resident"] is False and info["served"] == 0
-    serve(engine, [Workload(kind="cv", dataset=h1, y=y)])
+    run_workloads(engine, [Workload(kind="cv", dataset=h1, y=y)])
     (info,) = engine.datasets()
     assert info["resident"] is True and info["served"] == 1 and info["nbytes"] > 0
 
@@ -364,12 +359,12 @@ def test_handle_pin_warmup_evict(problem):
     assert engine.datasets()[0]["resident"] is False
     # a handle workload transparently rebuilds the evicted plan
     built = engine.plans_built
-    (resp,) = serve(engine, [Workload(kind="cv", dataset=h, y=y)])
+    (resp,) = run_workloads(engine, [Workload(kind="cv", dataset=h, y=y)])
     assert isinstance(resp, CVResponse)
     assert engine.plans_built == built + 1
     engine.evict(h, deregister=True)
     with pytest.raises(KeyError, match="not registered"):
-        serve(engine, [Workload(kind="cv", dataset=h, y=y)])
+        run_workloads(engine, [Workload(kind="cv", dataset=h, y=y)])
 
 
 def test_unregistered_handle_fails_clearly(problem):
@@ -377,7 +372,7 @@ def test_unregistered_handle_fails_clearly(problem):
     other = CVEngine()
     h = other.register(x, f, LAM)
     with pytest.raises(KeyError, match="not registered"):
-        serve(CVEngine(), [Workload(kind="cv", dataset=h, y=y)])
+        run_workloads(CVEngine(), [Workload(kind="cv", dataset=h, y=y)])
 
 
 # ---------------------------------------------------------------------------
@@ -415,9 +410,9 @@ def test_rdm_memo_stable_across_plan_variants(problem):
     engine = CVEngine()
     spec = DatasetSpec(x, foldlib.stratified_kfold(yc, K, seed=0), LAM)
     w = Workload(kind="rsa", dataset=spec, y=yc, num_classes=3, adjust_bias=False)
-    serve(engine, [w])  # builds the with_train_block=False plan
-    serve(engine, [Workload(kind="cv", dataset=spec, y=y)])  # superset plan now resident
-    serve(engine, [w])  # resolves via the superset key; must still hit
+    run_workloads(engine, [w])  # builds the with_train_block=False plan
+    run_workloads(engine, [Workload(kind="cv", dataset=spec, y=y)])  # superset plan now resident
+    run_workloads(engine, [w])  # resolves via the superset key; must still hit
     assert engine.stats()["rdm_hits"] == 1
     assert engine.stats()["rdm_entries"] == 1
 
@@ -427,7 +422,7 @@ def test_rdm_memo_streaming_and_batch_share_entries(problem):
     engine = CVEngine()
     spec = DatasetSpec(x, foldlib.stratified_kfold(yc, K, seed=0), LAM)
     w = Workload(kind="rsa", dataset=spec, y=yc, num_classes=3)
-    (batch,) = serve(engine, [w])
+    (batch,) = run_workloads(engine, [w])
     events = list(stream_workload(engine, w))
     assert engine.stats()["rdm_hits"] == 1  # the stream reused the memo
     np.testing.assert_array_equal(
@@ -463,7 +458,7 @@ def test_traffic_record_replay_roundtrip(tmp_path, problem):
     loaded.replay(engine, h, pin=True)
     warm = engine.compile_count()
     plans = engine.stats()["plans_built"]
-    serve(engine, [
+    run_workloads(engine, [
         Workload(kind="cv", dataset=h, y=y),
         Workload(kind="cv", dataset=h, y=yc, estimator="multiclass", num_classes=3),
         Workload(kind="permutation", dataset=h, y=y, n_perm=12, seed=0),
@@ -490,7 +485,7 @@ def test_traffic_log_records_static_options(problem):
     h = engine.register(x, foldlib.stratified_kfold(yc, K, seed=0), LAM)
     log.replay(engine, h)
     warm = engine.compile_count()
-    serve(engine, [
+    run_workloads(engine, [
         Workload(kind="cv", dataset=h, y=y, adjust_bias=False),
         Workload(kind="rsa", dataset=h, y=yc, num_classes=3, contrast="multiclass"),
     ])
@@ -610,8 +605,9 @@ def test_async_stream_equals_sync_stream(problem):
 
 
 def test_client_transport_validation(problem):
-    with pytest.raises(ValueError, match="transport"):
-        Client(transport="carrier-pigeon")
+    for unknown in ("carrier-pigeon", "thread"):  # the thread-queue transport is gone
+        with pytest.raises(ValueError, match="sync.*async"):
+            Client(transport=unknown)
     c = Client(transport="async")
     with pytest.raises(RuntimeError, match="async with"):
         with c:
