@@ -34,7 +34,9 @@ __all__ = [
     "distributed_gram",
     "distributed_hat_matrix",
     "distributed_permutation_binary",
+    "mesh_null_body",
     "mesh_null_program",
+    "mesh_permutation_program",
     "whole_shards",
     "sharded_null_from_plan",
     "replicated",
@@ -112,19 +114,14 @@ def distributed_permutation_binary(
                                       perm_lib.p_value(observed, null))
 
 
-def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
-                      perm_axes: tuple = ("data",), adjust_bias: bool = True):
-    """One jitted program (``jit__mesh_null``): (plan, y_perm (B, N)) → (B,)
-    null metrics of the permuted label rows, replicated on every device of
-    ``mesh``.
+def mesh_null_body(mesh: Mesh, *, metric: str, perm_axes: tuple, adjust_bias: bool):
+    """(plan, y_perm (B, N)) → (B,) replicated null: the draws padded (with
+    the last draw) to whole shards over ``perm_axes``, evaluated by
+    :func:`sharded_null_from_plan`, gathered (the all-gather is an op of
+    the program that traces it) and cut back to B, in the default float
+    dtype."""
+    from repro.core import permutation as perm_lib
 
-    Inside the program the draws are padded (with the last draw) to a whole
-    number of shards over ``perm_axes``, evaluated by
-    :func:`sharded_null_from_plan`, gathered to a replicated result (the
-    all-gather is an op of the program) and cut back to B. The returned
-    callable makes ``mesh`` ambient around the call, which the body's
-    gathers need; a caller holds on to it so each shape compiles once.
-    """
     def _mesh_null(plan, y_perm):
         b = y_perm.shape[0]
         t_pad = whole_shards(b, mesh, perm_axes)
@@ -132,15 +129,54 @@ def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
             y_perm = jnp.pad(y_perm, ((0, t_pad - b), (0, 0)), mode="edge")
         null = sharded_null_from_plan(plan, y_perm, mesh, metric=metric,
                                       perm_axes=perm_axes, adjust_bias=adjust_bias)
-        return replicated(null, mesh)[:b]
+        return perm_lib.as_default_float(replicated(null, mesh)[:b])
 
-    program = jax.jit(_mesh_null, out_shardings=NamedSharding(mesh, P()))
+    return _mesh_null
 
-    def call(plan, y_perm):
-        with _ambient(mesh):
-            return program(plan, y_perm)
 
-    return call
+class _OnMesh:
+    """A jitted program called with its mesh ambient, which the gathers of
+    a ``shard_map`` body need; ``_cache_size`` counts its compiled shapes."""
+
+    def __init__(self, mesh: Mesh, program):
+        self.mesh, self.program = mesh, program
+
+    def __call__(self, *args):
+        with _ambient(self.mesh):
+            return self.program(*args)
+
+    def _cache_size(self) -> int:
+        return self.program._cache_size()
+
+
+def mesh_null_program(mesh: Mesh, *, metric: str = "accuracy",
+                      perm_axes: tuple = ("data",), adjust_bias: bool = True):
+    """One jitted program (``jit__mesh_null``): (plan, y_perm (B, N)) → (B,)
+    null metrics of the permuted label rows, replicated on every device of
+    ``mesh``: :func:`mesh_null_body` (pad, sharded eval, all-gather,
+    slice). A caller holds on to it so each shape compiles once.
+    """
+    body = mesh_null_body(mesh, metric=metric, perm_axes=perm_axes, adjust_bias=adjust_bias)
+    return _OnMesh(mesh, jax.jit(body, out_shardings=NamedSharding(mesh, P())))
+
+
+def mesh_permutation_program(mesh: Mesh, evaluate, *, null):
+    """A whole permutation test on ``mesh`` as one program, also named
+    ``jit__mesh_null``: :func:`repro.core.permutation.permutation_program`
+    around the bodies it is handed, the observed labels evaluated
+    replicated by ``evaluate`` and the draws by ``null`` (a body that
+    shards them, such as :func:`mesh_null_body`). The labels, key and
+    ``n_perm`` enter replicated through the program's input shardings; the
+    packed test comes back replicated, so one transfer from any device
+    reads it.
+    """
+    from repro.core import permutation as perm_lib
+
+    rep = NamedSharding(mesh, P())
+    program = perm_lib.permutation_program(
+        evaluate, null=null, name="_mesh_null",
+        in_shardings=(None, rep, rep, rep), out_shardings=rep)
+    return _OnMesh(mesh, program)
 
 
 def whole_shards(b: int, mesh: Mesh, perm_axes: tuple) -> int:
@@ -160,8 +196,9 @@ def sharded_null_from_plan(plan: fastcv.CVPlan, y_perm: jax.Array, mesh: Mesh, *
     (T,) result stays sharded over ``perm_axes``: gather it with
     :func:`replicated` before slicing it.
 
-    This is the body of :func:`mesh_null_program`, the serve engine's mesh
-    null path, which pads, gathers and slices around it in one program.
+    This is the body of :func:`mesh_null_program` and
+    :func:`mesh_permutation_program`, the serve engine's mesh null paths,
+    which pad, gather and slice around it in one program.
     Called on its own, outside a jit, every op dispatches by itself. The
     plan enters as a replicated operand, not a closure: a closed-over
     array sharded on ``Explicit`` mesh axes (what ``jax.make_mesh`` gives)

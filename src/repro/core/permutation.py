@@ -10,6 +10,12 @@ engine shards over the ("pod", "data") mesh axes. A draw is the permuted
 label row itself (:func:`permutation_draws`), so a null reads its labels
 directly instead of gathering them through an index row.
 
+The serve engine composes the draws with the rest of a test in
+:func:`permutation_program`: draws, observed evaluation, null and p-value
+in one jitted program per shape bucket, read back with one fetch
+(:func:`unpack_test`). Its streamed null draws with
+:func:`permutation_draws` directly, chunk by chunk.
+
 Standard-approach baselines (retrain K models per permutation) are provided
 for the benchmark comparison (Fig. 3 right panels, Fig. 4).
 """
@@ -21,6 +27,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import fastcv, lda, metrics, multiclass
 from repro.core.folds import Folds
@@ -35,19 +42,49 @@ __all__ = [
     "analytical_permutation_multiclass",
     "standard_permutation_multiclass",
     "p_value",
+    "prng_key",
+    "permutation_test",
+    "permutation_program",
+    "unpack_test",
+    "as_default_float",
 ]
 
 
 class PermutationResult(NamedTuple):
+    """Observed metric, null and p-value. The library's functions return
+    device arrays; the serve engine returns host NumPy values, cut from the
+    one array its permutation program hands back (:func:`unpack_test`)."""
+
     observed: jax.Array    # () metric on unpermuted labels
     null: jax.Array        # (T,) null distribution
     p: jax.Array           # () permutation p-value
 
 
-def p_value(observed: jax.Array, null: jax.Array) -> jax.Array:
-    """(1 + #{null >= obs}) / (1 + T) — standard permutation p-value."""
-    t = null.shape[0]
-    return (1.0 + jnp.sum(null >= observed)) / (1.0 + t)
+def p_value(observed: jax.Array, null: jax.Array, n_perm=None) -> jax.Array:
+    """(1 + #{null >= obs}) / (1 + T) — standard permutation p-value.
+
+    With ``n_perm`` (a traced scalar is fine) only the first ``n_perm``
+    rows of ``null`` count, and T is ``n_perm``: a null evaluated at a
+    padded bucket size gives the p-value of its requested prefix."""
+    if n_perm is None:
+        return (1.0 + jnp.sum(null >= observed)) / (1.0 + null.shape[0])
+    counted = jnp.arange(null.shape[0]) < n_perm
+    return (1.0 + jnp.sum((null >= observed) & counted)) / (1.0 + n_perm)
+
+
+def prng_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s raw key (uint32 (2,)), made on the host.
+
+    Bit for bit the key JAX makes from a Python integer, under either
+    setting of ``jax_enable_x64``: the seed's two 32-bit halves, the high
+    one zero unless x64 is on. It enters a program as an argument, so a
+    request's seed costs no device dispatch of its own.
+    """
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        raise ValueError("prng_key makes threefry2x32 keys only")
+    s = int(np.int64(seed)) + int(jax.config.jax_random_seed_offset)
+    hi = (s >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, s & 0xFFFFFFFF], np.uint32)
 
 
 @partial(jax.jit, static_argnames=("n_perm",))
@@ -100,6 +137,57 @@ def null_binary_metrics(plan: fastcv.CVPlan, y_perm: jax.Array, *,
     yp = y_perm.T                                             # (N, B)
     dv = fastcv.binary_dvals(plan, yp, adjust_bias=adjust_bias)
     return _fold_metric_binary(dv, yp[plan.te_idx], metric)
+
+
+def permutation_test(plan, y: jax.Array, key: jax.Array, n_perm, *, t_gen: int,
+                     evaluate, null=None) -> jax.Array:
+    """One permutation test, traced into the caller's program.
+
+    Draws ``t_gen`` permuted label rows of ``y`` (:func:`permutation_draws`),
+    evaluates ``y`` itself as one row with ``evaluate(plan, rows) -> (B,)``
+    and the draws with ``null`` (``evaluate`` when None), and counts the
+    first ``n_perm`` draws into the p-value. Returns
+    ``[observed, p, null (t_gen,)]`` packed in one array of JAX's default
+    float dtype, so the caller reads the whole test back with one transfer
+    (:func:`unpack_test`).
+    """
+    null = evaluate if null is None else null
+    draws = permutation_draws(key, y, t_gen)
+    observed = evaluate(plan, y[None])[0]
+    dist = null(plan, draws)
+    p = p_value(observed, dist, n_perm)
+    return jnp.concatenate([as_default_float(a) for a in (observed[None], p[None], dist)])
+
+
+def as_default_float(a: jax.Array) -> jax.Array:
+    """``a`` in JAX's default float dtype (float32, float64 under x64): the
+    one dtype of the engine's permutation metrics, so a packed test keeps
+    its p-value's precision and a streamed null the one-shot null's dtype."""
+    return a.astype(jnp.result_type(float))
+
+
+def permutation_program(evaluate, *, null=None, name: str = "_eval", **jit_options):
+    """jit[(plan, y, key, n_perm, t_gen) -> packed test] of
+    :func:`permutation_test` around the bodies it is handed; all arguments
+    positional, ``t_gen`` static.
+
+    ``n_perm`` is traced, so every request within one bucket ``t_gen``
+    runs one compiled program. ``name`` names the XLA module
+    (``jit_<name>``); ``jit_options`` go to :func:`jax.jit` (the mesh path
+    passes its shardings).
+    """
+    def program(plan, y, key, n_perm, t_gen):
+        return permutation_test(plan, y, key, n_perm, t_gen=t_gen, evaluate=evaluate,
+                                null=null)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, static_argnums=4, **jit_options)
+
+
+def unpack_test(packed: np.ndarray, n_perm: int) -> PermutationResult:
+    """The host-side :class:`PermutationResult` of a fetched
+    :func:`permutation_test`: scalars and the null's first ``n_perm`` rows."""
+    return PermutationResult(packed[0], packed[2:2 + n_perm], packed[1])
 
 
 def analytical_permutation_binary(

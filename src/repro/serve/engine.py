@@ -194,6 +194,29 @@ class EngineConfig:
                 "has no mixed-precision path yet)")
 
 
+def _binary_rows(metric: str, adjust_bias: bool):
+    """(plan, y_rows (B, N)) -> (B,) binary metrics of label rows, in the
+    default float dtype (:func:`repro.core.permutation.as_default_float`)."""
+
+    def _eval(plan, y_rows):
+        return perm_lib.as_default_float(perm_lib.null_binary_metrics(
+            plan, y_rows, metric=metric, adjust_bias=adjust_bias))
+
+    return _eval
+
+
+def _multiclass_rows(num_classes: int):
+    """(plan, y_rows (B, N) int) -> (B,) multi-class accuracies of label
+    rows, in the default float dtype."""
+
+    def _eval(plan, y_rows):
+        preds = multiclass.batch_predict(plan, y_rows, num_classes)
+        y_te = y_rows[:, plan.te_idx]  # (B, K, m)
+        return perm_lib.as_default_float(jax.vmap(metrics.multiclass_accuracy)(preds, y_te))
+
+    return _eval
+
+
 class CVEngine:
     """Multi-tenant analytical-CV evaluation engine."""
 
@@ -235,6 +258,9 @@ class CVEngine:
         self._perm_binary = {}  # (metric, adjust_bias) -> jit -> (B,)
         self._mesh_null = {}  # (metric, adjust_bias) -> mesh jit -> (B,)
         self._perm_multiclass = {}  # num_classes -> jit -> (B,)
+        # one whole permutation test per program: (path, estimator, metric,
+        # adjust_bias, num_classes) -> jit, one entry per bucket (static t_gen)
+        self._perm_tests = {}
         self._rsa_pairs = {}  # (dissim, adjust_bias, donate, fused) -> jit
         self._rsa_score = {}  # method -> jit[(emp, models) -> (M,)]
         self._rsa_null = {}  # method -> jit[(emp, models, perms) -> (M,T)]
@@ -825,11 +851,15 @@ class CVEngine:
                 rows = jnp.tile(y_mc[None, :], (b, 1))
                 outs.append(self.eval_multiclass(plan, rows, num_classes))
             if "permutation" in tasks:
+                # the one-shot test's program, then the streamed null's chunk
+                outs.append(self._warm_test(plan, y_bin, b, "binary", metric, adjust_bias, 0))
                 draws = perm_lib.permutation_draws(jax.random.PRNGKey(0), y_bin, b)
                 outs.append(
                     self.null_binary(plan, draws, metric=metric, adjust_bias=adjust_bias)
                 )
                 if num_classes >= 2:  # mirrors the observed_multiclass gate above
+                    outs.append(self._warm_test(plan, y_mc, b, "multiclass", metric,
+                                                adjust_bias, num_classes))
                     draws = perm_lib.permutation_draws(jax.random.PRNGKey(0), y_mc, b)
                     outs.append(self.null_multiclass(plan, draws, num_classes=num_classes))
             if "rsa" in tasks:
@@ -1059,12 +1089,8 @@ class CVEngine:
         """jit[(plan, y_perm (B, N)) -> (B,) metrics] of permuted label rows."""
         fn = self._perm_binary.get((metric, adjust_bias))
         if fn is None:
-
-            def _eval(plan, y_perm):
-                return perm_lib.null_binary_metrics(plan, y_perm, metric=metric,
-                                                    adjust_bias=adjust_bias)
-
-            fn = self._perm_binary[(metric, adjust_bias)] = jax.jit(_eval)
+            fn = self._perm_binary[(metric, adjust_bias)] = jax.jit(
+                _binary_rows(metric, adjust_bias))
         return fn
 
     def _mesh_null_fn(self, metric: str, adjust_bias: bool):
@@ -1091,14 +1117,92 @@ class CVEngine:
         """jit[(plan, y_rows (B, N) int) -> (B,) accuracies] of label rows."""
         fn = self._perm_multiclass.get(num_classes)
         if fn is None:
-
-            def _eval(plan, y_rows):
-                preds = multiclass.batch_predict(plan, y_rows, num_classes)
-                y_te = y_rows[:, plan.te_idx]  # (B, K, m)
-                return jax.vmap(metrics.multiclass_accuracy)(preds, y_te)
-
-            fn = self._perm_multiclass[num_classes] = jax.jit(_eval)
+            fn = self._perm_multiclass[num_classes] = jax.jit(_multiclass_rows(num_classes))
         return fn
+
+    def _perm_test_fn(self, path: str, estimator: str, metric: str, adjust_bias: bool,
+                      num_classes: int):
+        """The one program of a whole permutation test
+        (:func:`repro.core.permutation.permutation_program`): draws,
+        observed eval, null and p-value, packed. Its null is the engine's
+        own :meth:`null_binary` or :meth:`null_multiclass`, traced in, so a
+        one-shot test and a streamed chunk run one null. Local programs
+        compile to ``jit__eval``, mesh ones to ``jit__mesh_null``."""
+        key = (path, estimator, metric, adjust_bias, num_classes)
+        fn = self._perm_tests.get(key)
+        if fn is None:
+            if estimator == "multiclass":
+                evaluate = _multiclass_rows(num_classes)
+
+                def null(plan, rows):
+                    return self.null_multiclass(plan, rows, num_classes=num_classes)
+            else:
+                evaluate = _binary_rows(metric, adjust_bias)
+
+                def null(plan, rows):
+                    return self.null_binary(plan, rows, metric=metric, adjust_bias=adjust_bias)
+            if path == "mesh":
+                from repro.core.distributed import mesh_permutation_program
+
+                fn = mesh_permutation_program(self.config.mesh, evaluate, null=null)
+            else:
+                fn = perm_lib.permutation_program(evaluate, null=null)
+            self._perm_tests[key] = fn
+        return fn
+
+    def _test_inputs(self, plan, y, key, estimator: str, adjust_bias: bool):
+        """(path, plan, labels, raw key) as the one permutation program takes
+        them. The labels are cast where they live: on the host unless the
+        caller handed a device array. An integer ``key`` is a seed, made into
+        ``jax.random.PRNGKey(key)``'s key on the host."""
+        if estimator == "multiclass":
+            path, dtype = "local", np.int32  # the 3-class null runs locally
+        else:
+            path = "local" if self.config.mesh is None else "mesh"
+            if not adjust_bias:
+                plan = self._strip_train(plan)
+            dtype = plan.h.dtype
+        y = y.astype(dtype) if isinstance(y, jax.Array) else np.asarray(y, dtype)
+        if isinstance(key, (int, np.integer)):
+            key = perm_lib.prng_key(key)
+        return path, plan, y, key
+
+    def _warm_test(self, plan, y, t_gen: int, estimator: str, metric: str,
+                   adjust_bias: bool, num_classes: int):
+        """Compile the one permutation program of bucket ``t_gen`` (warm-up:
+        dispatched, never fetched or counted)."""
+        path, plan, y, key = self._test_inputs(plan, y, 0, estimator, adjust_bias)
+        fn = self._perm_test_fn(path, estimator, metric, adjust_bias, num_classes)
+        return fn(plan, y, key, np.int32(t_gen), t_gen)
+
+    def _permutation_test(self, plan, y, n_perm: int, key, *, estimator: str,
+                          metric: str = "accuracy", adjust_bias: bool = True,
+                          num_classes: int = 0) -> perm_lib.PermutationResult:
+        """One permutation test as one program dispatch and one fetch.
+
+        The draws are made at the bucket size ``bucket_size(n_perm)`` and
+        ``n_perm`` enters traced, so every request within a bucket runs one
+        compiled program with the library's draws. The input casts and the
+        program — draws, observed eval, null and p-value — are one
+        ``null_chunk`` span; the fetch follows it, outside, as the caller's
+        read of a result did before.
+        """
+        t_gen = bucket_size(n_perm, self.config.buckets)
+        with self.tracer.span("null_chunk"):
+            path, plan, y, key = self._test_inputs(plan, y, key, estimator, adjust_bias)
+            fn = self._perm_test_fn(path, estimator, metric, adjust_bias, num_classes)
+            self._count_step2(plan, 1 + t_gen, num_classes)  # observed row + draws
+            packed = self.tracer.sync(fn(plan, y, key, np.int32(n_perm), t_gen))
+        packed = jax.device_get(packed)
+        if path == "mesh":
+            from repro.core.distributed import whole_shards
+
+            rows = whole_shards(t_gen, self.config.mesh, self.config.perm_axes)
+        else:
+            rows = t_gen
+        self.metrics.inc("permutation_programs_total", path=path)
+        self._count_null(path, n_perm, rows)
+        return perm_lib.unpack_test(packed, n_perm)
 
     def observed_binary(
         self,
@@ -1108,7 +1212,7 @@ class CVEngine:
         metric: str = "accuracy",
         adjust_bias: bool = True,
     ) -> jax.Array:
-        """Observed (unpermuted) binary metric through the permutation path."""
+        """Observed (unpermuted) binary metric, for the streamed null."""
         # The span covers the dispatch preamble (dtype cast, padding) too —
         # each is a device dispatch that would otherwise show up as an
         # untraced gap in the span timeline.
@@ -1130,22 +1234,35 @@ class CVEngine:
     ) -> jax.Array:
         """Null metrics for a (B, N) batch of permuted label rows → (B,).
 
-        The chunk-level building block under both :meth:`permutation_binary`
-        and the streaming front-end. On a mesh-configured engine the batch
-        shards over ``perm_axes`` in one jitted program per shape (padded
-        up to a whole number of shards, gathered, trimmed back) — so
-        *streamed* null chunks use the mesh exactly like monolithic
-        requests, with identical draws. Locally, the batch pads up to a
-        shape bucket and repeats never recompile.
+        The engine's one binary null. The program of a one-shot test
+        (:meth:`permutation_binary`) traces it as its null body: called
+        with traced rows it composes the null into that program and no
+        more, since that program's dispatch is spanned and counted where it
+        is made. Called with rows that exist — a streamed chunk — it
+        dispatches the null by itself. On a mesh-configured engine the
+        batch shards over ``perm_axes`` (padded up to a whole number of
+        shards, gathered, trimmed back), in the test's program or in one
+        jitted program per shape, so streamed chunks use the mesh exactly
+        like monolithic requests, with identical draws. Locally, the batch
+        pads up to a shape bucket and repeats never recompile.
 
         ``requested`` is how many leading rows of ``y_perm`` were asked for
         (all of them by default); the rest, and the rows the path pads on,
         count as padding in ``null_pad_draws_total``.
         """
+        if not adjust_bias:
+            plan = self._strip_train(plan)
+        if isinstance(y_perm, jax.core.Tracer):
+            y_perm = y_perm.astype(plan.h.dtype)
+            if self.config.mesh is None:
+                return _binary_rows(metric, adjust_bias)(plan, y_perm)
+            from repro.core.distributed import mesh_null_body
+
+            return mesh_null_body(self.config.mesh, metric=metric,
+                                  perm_axes=self.config.perm_axes,
+                                  adjust_bias=adjust_bias)(plan, y_perm)
         b = y_perm.shape[0]
         with self.tracer.span("null_chunk"):
-            if not adjust_bias:
-                plan = self._strip_train(plan)
             y_perm = y_perm.astype(plan.h.dtype)
             if self.config.mesh is not None:
                 from repro.core.distributed import whole_shards
@@ -1185,7 +1302,11 @@ class CVEngine:
         requested: Optional[int] = None,
     ) -> jax.Array:
         """Multi-class analogue of :meth:`null_binary`: (B, N) permuted
-        label rows → (B,) accuracies, on the local path."""
+        label rows → (B,) accuracies, on the local path; traced rows
+        compose the null into the test's program
+        (:meth:`permutation_multiclass`)."""
+        if isinstance(y_rows, jax.core.Tracer):
+            return _multiclass_rows(num_classes)(plan, y_rows)
         with self.tracer.span("null_chunk"):
             fn = self._perm_multiclass_fn(num_classes)
             padded, b = self._pad_rows(y_rows, owned=True)
@@ -1197,64 +1318,42 @@ class CVEngine:
     def permutation_binary(
         self,
         plan: fastcv.CVPlan,
-        y: jax.Array,
+        y,
         n_perm: int,
-        key: jax.Array,
+        key,
         *,
         metric: str = "accuracy",
         adjust_bias: bool = True,
     ) -> perm_lib.PermutationResult:
         """Algorithm 1 against a cached plan: observed + null + p-value.
 
-        With a mesh configured, the permutation batch shards over the
-        mesh's ``perm_axes``; otherwise it runs through the bucketed local
-        eval path. Either way the draws are generated at the bucket size,
-        so repeats never recompile and both paths evaluate the same draws.
+        One jitted program draws ``bucket_size(n_perm)`` label rows,
+        evaluates the observed labels and the draws (its null is
+        :meth:`null_binary`, traced in) and computes p; one
+        fetch brings the packed result back, so the returned fields are host
+        NumPy values (reading them transfers nothing). With a mesh
+        configured the draws' null shards over the mesh's ``perm_axes``
+        inside that program (``jit__mesh_null``); otherwise it runs locally
+        (``jit__eval``). Either way the draws are the library's for the same
+        key. ``key`` is a ``jax.random.PRNGKey`` or an integer seed.
         """
-        observed = self.observed_binary(plan, y, metric=metric, adjust_bias=adjust_bias)
-        # Generate directly at the bucket size: permutation_draws jits on
-        # static T, so bucketing T here is what keeps arbitrary
-        # client-chosen n_perm from compiling a fresh generator each time.
-        # Draw generation and the p-value are null-distribution work, so
-        # they count toward the null_chunk stage (timings() sums same-name
-        # top-level spans) — leaving them untraced would break the
-        # stage-sum ≈ end-to-end acceptance invariant.
-        t_gen = bucket_size(n_perm, self.config.buckets)
-        with self.tracer.span("null_chunk"):
-            # the labels the null reads, shuffled: cast before the draws
-            y = y.astype(plan.h.dtype)
-            if self.config.mesh is not None:
-                # every chip draws them all and keeps its shard: no transfer
-                key, y = jax.device_put(
-                    (key, y), NamedSharding(self.config.mesh, PartitionSpec()))
-            y_perm = self.tracer.sync(perm_lib.permutation_draws(key, y, t_gen))
-        # this API's contract (and the multiclass path) counts the
-        # *requested* draws only
-        null = self.null_binary(plan, y_perm, metric=metric, adjust_bias=adjust_bias,
-                                requested=n_perm)[:n_perm]
-        with self.tracer.span("null_chunk"):
-            p = self.tracer.sync(perm_lib.p_value(observed, null))
-        return perm_lib.PermutationResult(observed, null, p)
+        return self._permutation_test(plan, y, n_perm, key, estimator="binary",
+                                      metric=metric, adjust_bias=adjust_bias)
 
     def permutation_multiclass(
         self,
         plan: fastcv.CVPlan,
-        y: jax.Array,
+        y,
         n_perm: int,
-        key: jax.Array,
+        key,
         *,
         num_classes: int,
     ) -> perm_lib.PermutationResult:
-        """Algorithm 2 under permutations against a cached plan."""
-        observed = self.observed_multiclass(plan, y, num_classes=num_classes)
-        t_gen = bucket_size(n_perm, self.config.buckets)
-        with self.tracer.span("null_chunk"):
-            y_rows = self.tracer.sync(perm_lib.permutation_draws(key, y, t_gen))
-        null = self.null_multiclass(plan, y_rows, num_classes=num_classes,
-                                    requested=n_perm)[:n_perm]
-        with self.tracer.span("null_chunk"):
-            p = self.tracer.sync(perm_lib.p_value(observed, null))
-        return perm_lib.PermutationResult(observed, null, p)
+        """Algorithm 2 under permutations against a cached plan: as
+        :meth:`permutation_binary`, one local program and one fetch, with
+        the 3-class eval body."""
+        return self._permutation_test(plan, y, n_perm, key, estimator="multiclass",
+                                      num_classes=num_classes)
 
     # ------------------------------------------------------------------
     # Tuning (routed to the eigendecomposition-based LOO machinery)
@@ -1277,6 +1376,8 @@ class CVEngine:
             list(self._evals.values())
             + list(self._perm_binary.values())
             + list(self._perm_multiclass.values())
+            + list(self._mesh_null.values())
+            + list(self._perm_tests.values())
             + list(self._rsa_pairs.values())
             + list(self._rsa_score.values())
             + list(self._rsa_null.values())

@@ -111,6 +111,11 @@ METRICS = {
         "labels": ("path",),
         "help": "CV permutation-null rows evaluated as padding (to the bucket and to whole shards), by path",
     },
+    "permutation_programs_total": {
+        "kind": "counter",
+        "labels": ("path",),
+        "help": "Permutation tests served as one program and one fetch, by path (mesh or local)",
+    },
     "stage_latency_seconds": {
         "kind": "histogram",
         "labels": ("stage",),

@@ -34,6 +34,7 @@ def main():
     from bench.reference.draws import permutation_indices
     from repro.core import folds as foldlib
     from repro.serve import CVEngine, Client, EngineConfig, Workload
+    from repro.serve.workload import stream_workload
 
     assert len(jax.devices()) == 4, jax.devices()
     rng = np.random.default_rng(0)
@@ -63,11 +64,17 @@ def main():
 
     r_mesh = analysis(mesh_client, h_mesh, SEED)
     r_one = analysis(one_client, h_one, SEED)
+    # the test's one program hands its packed result back replicated
+    program = mesh_engine._perm_tests[("mesh", "binary", "accuracy", True, 0)]
+    _, plan = mesh_engine.resolve(h_mesh)
+    packed = program(plan, y, jax.random.PRNGKey(SEED), np.int32(N_PERM), 64)
     out["null"] = {"mesh": np.asarray(r_mesh.null).tolist(),
                    "one": np.asarray(r_one.null).tolist(),
                    "observed": [float(r_mesh.observed), float(r_one.observed)],
                    "p": [float(r_mesh.p), float(r_one.p)],
-                   "null_devices": len(r_mesh.null.sharding.device_set)}
+                   "null_devices": len(packed.sharding.device_set),
+                   "host": all(isinstance(v, (np.ndarray, np.generic))
+                               for v in (r_mesh.null, r_mesh.observed, r_mesh.p))}
 
     # the float64 refit per fold, on the observed labels and sampled draws
     draws = np.sort(rng.choice(N_PERM, 16, replace=False))
@@ -91,7 +98,6 @@ def main():
     out["second_analysis_programs"] = len(made)
 
     # a chunk that is no whole number of shards: padded, then cut back
-    _, plan = mesh_engine.resolve(h_mesh)
     chunk = y[permutation_indices(SEED, N, 30)]
     part = mesh_engine.null_binary(plan, jax.numpy.asarray(chunk))
     out["chunk"] = {"size": int(part.shape[0]),
@@ -103,6 +109,14 @@ def main():
                        "local_draws": one_engine.metrics.get("null_draws_total").value(path="local"),
                        "local_pads": one_engine.metrics.get("null_pad_draws_total").value(path="local"),
                        "labels_evaluated": mesh_engine.labels_evaluated}
+
+    # the same test streamed in chunks on the mesh (the chunked path),
+    # after the counters are read
+    done = list(stream_workload(mesh_engine, Workload(kind="permutation", dataset=h_mesh, y=y,
+                                                      n_perm=N_PERM, seed=SEED), chunk=16))[-1]
+    streamed = done.payload
+    out["stream"] = {"null": np.asarray(streamed.null).tolist(),
+                     "observed": float(streamed.observed), "p": float(streamed.p)}
 
     # a live Mesh still works, with the default perm_axes ("data",)
     live = jax.make_mesh((2, 2), ("data", "model"))

@@ -46,7 +46,17 @@ def test_mesh_null_equals_the_one_device_null(readings):
     assert len(r["mesh"]) == 50
     assert r["mesh"] == r["one"]
     assert r["observed"][0] == r["observed"][1] and r["p"][0] == r["p"][1]
-    assert r["null_devices"] == 4  # the gathered null is on every device
+    assert r["null_devices"] == 4  # the one program's packed test is on every device
+    assert r["host"]  # and the response holds host arrays cut from it
+
+
+def test_mesh_one_program_equals_the_mesh_streamed_null(readings):
+    # the same test streamed in chunks on the mesh, each chunk a dispatch
+    # of its own: the one program's null, observed metric and p-value
+    r, s = readings["null"], readings["stream"]
+    np.testing.assert_allclose(r["mesh"], s["null"], rtol=0, atol=1e-12)
+    assert abs(r["observed"][0] - s["observed"]) < 1e-12
+    assert abs(r["p"][0] - s["p"]) < 1e-12
 
 
 def test_sampled_draws_match_the_float64_refit(readings):
