@@ -15,7 +15,8 @@ size and load, with a short window:
   (:data:`bench.reference.refit.ONE_PASS`) put in the program's place on
   the label vectors that seed's window served, and the program with its
   own lower-precision path switched on (``precision="bf16_gram"``, the
-  Gram from bfloat16 inputs);
+  Gram from bfloat16 inputs), skipped with one line saying so where the
+  engine has no such path (``gram_impl="distributed"``);
 * with ``--one-pass-program``, instead of the above, the program itself
   with the TPU's default one-pass precision planted in place of the
   ``HIGHEST`` it gives every float32 contraction (its
@@ -70,6 +71,13 @@ def one(cell, seed, seconds, kind, precision=None):
     return keep
 
 
+def bf16_gram_skip(cell):
+    """Why the program's ``bf16_gram`` control cannot run in ``cell``, or None."""
+    if cell.config["engine"].get("gram_impl") == "distributed":
+        return "the engine has no bf16_gram path with gram_impl='distributed'"
+    return None
+
+
 def plant_one_pass() -> None:
     """Make every float32 contraction of the program one bfloat16 pass."""
     import importlib
@@ -111,6 +119,9 @@ def main(argv=None) -> int:
         return 0
     for seed in _seeds(args.seeds):
         one(cell, seed, args.seconds, "program")
+    skip = bf16_gram_skip(cell)
+    if skip and args.control_seeds:
+        print(json.dumps({"kind": "control_bf16_gram", "skipped": skip}), flush=True)
     for seed in _seeds(args.control_seeds):
         keep = one(cell, seed, args.seconds, "program")
         if keep["products"] is not None:
@@ -123,7 +134,8 @@ def main(argv=None) -> int:
         correct, _ = check.verdict(numbers, cell.limits)
         print(json.dumps({"kind": "control_one_pass", "seed": seed, "checks": numbers,
                           "correct": correct}), flush=True)
-        one(cell, seed, args.seconds, "control_bf16_gram", precision="bf16_gram")
+        if not skip:
+            one(cell, seed, args.seconds, "control_bf16_gram", precision="bf16_gram")
     for rate in [float(r) for r in args.rates.split(",") if r]:
         cell.traffic["rate_per_s"] = rate
         for run in range(args.rate_runs):

@@ -6,12 +6,12 @@ drawn from the run's seed is recomputed by :mod:`bench.reference.refit`
 number has its limit in ``bench/limits/<cell>.json``.
 
 ``flips_per_1k``
-    Closed loops. For each checked visit, the label vectors it produced
-    answers for — the ``cv`` labels (every prediction compared), the
-    observed labels and a sample of the permutation draws (accuracy
-    compared) — and the test trials on which program and reference
-    disagree, counted per 1000 label vectors. The draws are regenerated
-    from the visit's seed by :mod:`bench.reference.draws`.
+    Closed loops. Each step of a checked visit that produces answers
+    compares them in its own ``reference`` (``bench/steps/<step>.py``) and
+    gives the test trials on which program and reference disagree, and the
+    label vectors it compared: the ``cv`` labels (every prediction
+    compared), the observed labels and a sample of the permutation draws
+    (accuracy compared). The trials are counted per 1000 label vectors.
 ``dv_rel_dev``
     The open HTTP loop. For each sampled request, the largest gap between
     the served binary-LDA decision values and the reference's, over the
@@ -27,62 +27,49 @@ from __future__ import annotations
 import numpy as np
 
 from bench.reference import refit
-from bench.reference.draws import permutation_indices
 
-__all__ = ["check_closed", "check_open", "verdict"]
+__all__ = ["accuracy", "check_closed", "check_open", "sample", "verdict"]
 
 
-def _sample(rng, count: int, k: int) -> np.ndarray:
+def sample(rng, count: int, k: int) -> np.ndarray:
+    """``min(k, count)`` sorted indices of ``range(count)``, without repeats."""
     return np.sort(rng.choice(count, min(k, count), replace=False))
 
 
 def check_closed(cell, subjects, products, seed: int, control=None):
     """Numbers of a closed-loop run from the window's products.
 
-    With ``control`` (a :class:`bench.reference.refit.Precision`), the
-    reference computed at that precision answers the same label vectors in
-    the program's place.
+    The visits to check are drawn from the run's seed; each one's steps,
+    in the mix's order, compare their answers (``reference`` of each step
+    module, drawing from the same generator), and each number sums its
+    steps' ``(count, per)`` into ``1000 · count / per``. With ``control`` (a
+    :class:`bench.reference.refit.Precision`), the reference computed at
+    that precision answers the same label vectors in the program's place.
     """
     traffic = cell.traffic
     rng = np.random.default_rng([int(seed), 11])
-    visits = _sample(rng, len(products), int(traffic["check_visits"]))
-    trials = vectors = 0
-    for v in visits:
+    totals: dict = {}
+    for v in sample(rng, len(products), int(traffic["check_visits"])):
         prod = products[v]
         subj = subjects[prod["subject"]]
-        y, te = subj.y, subj.te
-        cols = [y]
-        got = []
-        if "null" in prod:
-            draws = _sample(rng, prod["n_perm"], int(traffic["check_draws"]))
-            perms = permutation_indices(prod["seed"], len(y), prod["n_perm"])[draws]
-            cols += list(y[perms])
-            got = [prod["observed"]] + list(np.asarray(prod["null"])[draws])
-        labels = np.stack(cols)  # (B, N)
-        acc, preds = _answers(subj, labels, refit.FLOAT64)
-        cv = prod.get("cv")
-        if control is not None:
-            acc_c, preds_c = _answers(subj, labels, control)
-            got = list(acc_c[:len(got)])
-            cv = None if cv is None else preds_c[0]
-        if cv is not None:
-            trials += int((np.asarray(cv) != preds[0]).sum())
-            vectors += 1
-        if got:
-            gap = np.abs(np.asarray(got, np.float64) - acc[:len(got)]) * te.size
-            trials += float(np.rint(gap).sum())
-            vectors += len(got)
-    return {"flips_per_1k": 1000.0 * trials / max(vectors, 1)}
+        for step in traffic["visit"]:
+            reference = getattr(cell.steps[step["step"]], "reference", None)
+            if reference is None:
+                continue
+            for name, (count, per) in reference(subj, prod, rng, traffic, control).items():
+                total = totals.get(name, (0, 0))
+                totals[name] = (total[0] + count, total[1] + per)
+    return {name: 1000.0 * count / max(per, 1) for name, (count, per) in totals.items()}
 
 
-def _answers(subj, labels, prec):
-    """(accuracy per label row (B,), predictions (B, K, m) or None) of the refit."""
+def accuracy(subj, labels, prec) -> np.ndarray:
+    """The refit's accuracy over all test trials, per label row (B,) of ``labels`` (B, N)."""
     x, te, tr, lam = subj.x64, subj.te, subj.tr, subj.lam
     if subj.num_classes == 2:
         dv = refit.binary_dvals(x, labels.T, te, tr, lam, prec)
-        return refit.fold_accuracy(dv, labels.T[te]), None
+        return refit.fold_accuracy(dv, labels.T[te])
     preds, _ = refit.multiclass_predict(x, labels, te, tr, lam, subj.num_classes, prec)
-    return (preds == labels[:, te]).mean(axis=(1, 2)), preds
+    return (preds == labels[:, te]).mean(axis=(1, 2))
 
 
 def check_open(subject, sampled, control=None):

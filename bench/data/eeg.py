@@ -1,13 +1,15 @@
 """Simulated EEG/MEG subject at the source paper's §2.13 shape, made on the device.
 
 A copy of the repository's EEG simulator, kept with the benchmark so that
-no change to the program can move the data it is measured on. One jitted
+no change to the program can move the data it is measured on. The data
+generator ``eeg`` (a configuration's ``"generator": "eeg"``): one jitted
 call turns a key into one subject's windowed features:
 
-* epochs of ``n_channels`` channels sampled at ``fs`` Hz from ``t_min`` to
-  ``t_max`` seconds, a class-specific N170-like evoked component
-  (``snr``-scaled) in spatially correlated noise, baseline-corrected on the
-  pre-stimulus interval;
+* :func:`epochs`: ``n_channels`` channels sampled at ``fs`` Hz from
+  ``t_min`` to ``t_max`` seconds, a class-specific N170-like evoked
+  component (``snr``-scaled) in spatially correlated noise,
+  baseline-corrected on the pre-stimulus interval; a function of its own,
+  traced into a caller's jitted call, for other generators to reuse;
 * features: channel amplitudes averaged over consecutive post-stimulus
   windows of ``window_ms`` and concatenated (100 ms → P = 3800, 200 ms →
   P = 1900 at 380 channels).
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["n_features", "simulate"]
+__all__ = ["epochs", "n_features", "simulate"]
 
 
 def _post_windows(n_times: int, t_min: float, fs: float, window_ms: float) -> tuple[int, int, int]:
@@ -43,9 +45,9 @@ def n_features(shape: dict) -> int:
     return n_win * shape["n_channels"]
 
 
-@partial(jax.jit, static_argnames=("n_trials", "n_channels", "num_classes", "fs", "t_min",
-                                   "t_max", "window_ms", "snr"))
-def _simulate(key, *, n_trials, n_channels, num_classes, fs, t_min, t_max, window_ms, snr):
+def epochs(key, *, n_trials, n_channels, num_classes, fs, t_min, t_max, snr):
+    """Baseline-corrected epochs, float32 (n_trials, n_channels, n_times), and labels
+    int32 (n_trials,). Traced inside the caller's jitted call."""
     dtype = jnp.float32
     n_times = int(round((t_max - t_min) * fs)) + 1
     times = t_min + jnp.arange(n_times, dtype=dtype) / fs
@@ -61,12 +63,19 @@ def _simulate(key, *, n_trials, n_channels, num_classes, fs, t_min, t_max, windo
         jnp.asarray(n_channels, dtype))
     white = jax.random.normal(k_noise, (n_trials, n_channels, n_times), dtype)
     noise = jnp.einsum("cd,ndt->nct", mix, white, precision=jax.lax.Precision.HIGHEST)
-    epochs = snr * signal[y] + noise
+    ep = snr * signal[y] + noise
     pre = (times < 0).astype(dtype)
-    base = jnp.einsum("nct,t->nc", epochs, pre, precision=jax.lax.Precision.HIGHEST) / pre.sum()
-    epochs = epochs - base[:, :, None]
-    first, per_win, n_win = _post_windows(n_times, t_min, fs, window_ms)
-    post = epochs[:, :, first:first + n_win * per_win]
+    base = jnp.einsum("nct,t->nc", ep, pre, precision=jax.lax.Precision.HIGHEST) / pre.sum()
+    return ep - base[:, :, None], y
+
+
+@partial(jax.jit, static_argnames=("n_trials", "n_channels", "num_classes", "fs", "t_min",
+                                   "t_max", "window_ms", "snr"))
+def _simulate(key, *, n_trials, n_channels, num_classes, fs, t_min, t_max, window_ms, snr):
+    ep, y = epochs(key, n_trials=n_trials, n_channels=n_channels, num_classes=num_classes,
+                   fs=fs, t_min=t_min, t_max=t_max, snr=snr)
+    first, per_win, n_win = _post_windows(ep.shape[-1], t_min, fs, window_ms)
+    post = ep[:, :, first:first + n_win * per_win]
     feats = post.reshape(n_trials, n_channels, n_win, per_win).mean(-1)  # (N, ch, win)
     x = jnp.transpose(feats, (0, 2, 1)).reshape(n_trials, n_win * n_channels)
     return x, y

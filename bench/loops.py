@@ -7,12 +7,13 @@ two drivers:
     One in-process :class:`repro.serve.Client` (sync transport) runs
     *visits* back to back until the window closes. A visit is the mix's
     ``visit`` list of steps against one subject, cycling through the
-    subjects set-up simulated: ``register`` (the dataset), ``cv``,
-    ``permutation`` (with ``n_perm`` draws and a fresh seed per visit) and
-    ``release`` (the engine forgets the dataset and its plans, so the next
-    visit rebuilds). ``setup_steps`` run once per subject before the
-    window; ``warm_visits`` visits run before it too, so every program the
-    window uses is compiled or loaded from the cache in set-up.
+    subjects set-up simulated, with a fresh seed per visit. Each step is a
+    module of its own, ``bench/steps/<step>.py`` (:mod:`bench.cells`):
+    ``register`` (the dataset), ``cv``, ``permutation`` (with ``n_perm``
+    draws) and ``release`` (the engine forgets the dataset and its plans,
+    so the next visit rebuilds). ``setup_steps`` run once per subject
+    before the window; ``warm_visits`` visits run before it too, so every
+    program the window uses is compiled or loaded from the cache in set-up.
 
 ``open``
     Requests arrive on a fixed schedule (``rate_per_s``, Poisson gaps,
@@ -45,6 +46,7 @@ __all__ = ["ClosedLoop", "OpenHTTPLoop", "make_loop"]
 
 _SEED_STREAM_VISITS = 1
 _SEED_STREAM_WARM = 2
+_SEED_STREAM_SETUP = 4
 _MAX_VISITS = 1 << 16
 
 
@@ -65,67 +67,49 @@ class Subject:
             self.y = labels.astype(np.int32)
         self.handle = None
 
+    def workload_args(self) -> dict:
+        """The dataset, labels and estimator of a workload on this subject."""
+        binary = self.num_classes == 2
+        return dict(dataset=self.handle, y=self.y, estimator="binary" if binary else "multiclass",
+                    num_classes=0 if binary else self.num_classes)
+
 
 class ClosedLoop:
     """Closed loop of visits through one in-process sync ``Client``."""
 
-    def __init__(self, traffic: dict, engine, subjects: list, seed: int):
+    def __init__(self, traffic: dict, steps: dict, engine, subjects: list, seed: int):
         from repro.serve import Client
 
         self.traffic = traffic
+        self.steps = steps
         self.engine = engine
         self.client = Client(engine)
         self.subjects = subjects
         self.seeds = derived_seeds(seed, _SEED_STREAM_VISITS, _MAX_VISITS)
         self.warm_seeds = derived_seeds(seed, _SEED_STREAM_WARM, 64)
+        self.setup_seeds = derived_seeds(seed, _SEED_STREAM_SETUP, len(subjects))
         self.products: list = []
 
-    # -- steps ---------------------------------------------------------------
-
-    def _workload(self, step: dict, subj: Subject, seed: int):
-        from repro.serve import Workload
-
-        est = "binary" if subj.num_classes == 2 else "multiclass"
-        common = dict(dataset=subj.handle, y=subj.y, estimator=est,
-                      num_classes=0 if est == "binary" else subj.num_classes)
-        if step["step"] == "cv":
-            return Workload(kind="cv", **common)
-        return Workload(kind="permutation", n_perm=int(step["n_perm"]), seed=int(seed), **common)
+    def _step(self, step: dict, subj: Subject, seed: int) -> dict:
+        return self.steps[step["step"]].run(self.client, self.engine, subj, step, int(seed))
 
     def _visit(self, index: int, seed: int, annotate: bool) -> dict:
         j = index % len(self.subjects)
         subj = self.subjects[j]
         out = {"subject": j, "seed": int(seed), "labels": 0}
         for step in self.traffic["visit"]:
-            kind = step["step"]
-            with _annotate(f"bench.{kind}", annotate):
-                if kind == "register":
-                    subj.handle = self.client.register(subj.x, (subj.te, subj.tr), subj.lam)
-                elif kind == "release":
-                    self.engine.release(subj.handle)
-                    subj.handle = None
-                elif kind == "cv":
-                    resp = self.client.submit(self._workload(step, subj, seed))
-                    out["cv"] = np.asarray(resp.values)
-                    out["labels"] += 1
-                elif kind == "permutation":
-                    resp = self.client.submit(self._workload(step, subj, seed))
-                    out["null"] = np.asarray(resp.null)
-                    out["observed"] = float(resp.observed)
-                    out["n_perm"] = int(step["n_perm"])
-                    out["labels"] += out["null"].size + 1
-                else:
-                    raise ValueError(f"unknown step {kind!r} in the traffic mix")
+            with _annotate(f"bench.{step['step']}", annotate):
+                fields = self._step(step, subj, seed)
+                out["labels"] += fields.pop("labels", 0)
+                out.update(fields)
         return out
 
     # -- set-up and window ---------------------------------------------------
 
     def setup(self, seconds: float) -> None:
-        for subj in self.subjects:
+        for subj, seed in zip(self.subjects, self.setup_seeds):
             for step in self.traffic.get("setup_steps", []):
-                if step["step"] != "register":
-                    raise ValueError(f"set-up step {step['step']!r} is not supported")
-                subj.handle = self.client.register(subj.x, (subj.te, subj.tr), subj.lam)
+                self._step(step, subj, seed)
         for i in range(int(self.traffic.get("warm_visits", 1))):
             self._visit(i, self.warm_seeds[i], annotate=False)
 
@@ -248,8 +232,11 @@ class OpenHTTPLoop:
         return out
 
 
-def make_loop(traffic: dict, engine, subjects: list, seed: int):
-    loops = {"closed": ClosedLoop, "open": OpenHTTPLoop}
-    if traffic["loop"] not in loops:
-        raise ValueError(f"unknown loop {traffic['loop']!r}; expected one of {sorted(loops)}")
-    return loops[traffic["loop"]](traffic, engine, subjects, seed)
+def make_loop(cell, engine, subjects: list, seed: int):
+    """The cell's loop (its mix's ``loop``), with the step modules a closed loop runs."""
+    traffic = cell.traffic
+    if traffic["loop"] == "closed":
+        return ClosedLoop(traffic, cell.steps, engine, subjects, seed)
+    if traffic["loop"] == "open":
+        return OpenHTTPLoop(traffic, engine, subjects, seed)
+    raise ValueError(f"unknown loop {traffic['loop']!r}; expected one of ['closed', 'open']")

@@ -45,7 +45,6 @@ import numpy as np  # noqa: E402
 from repro.serve import CVEngine, EngineConfig  # noqa: E402  (the system under test)
 
 from bench import cells, check, loops, trace_reduce  # noqa: E402
-from bench.data import eeg  # noqa: E402
 from bench.labels import derived_seeds  # noqa: E402
 
 _SEED_STREAM_SUBJECTS = 3
@@ -98,12 +97,14 @@ def kfold(n: int, k: int, seed: int):
 
 
 def make_subjects(cell, seed: int) -> list:
+    """The mix's subjects, each made by the configuration's data generator from its
+    own seed; ``x`` is (…, N, P), with any grid axes in front."""
     cfg = cell.config
     count = int(cell.traffic["subjects"])
     out = []
     for s in derived_seeds(seed, _SEED_STREAM_SUBJECTS, count):
-        x, y = eeg.simulate(int(s), cfg["data"], int(cfg["num_classes"]))
-        te, tr = kfold(int(x.shape[0]), int(cfg["folds"]), int(s))
+        x, y = cell.generator.simulate(int(s), cfg["data"], int(cfg["num_classes"]))
+        te, tr = kfold(int(cfg["data"]["n_trials"]), int(cfg["folds"]), int(s))
         out.append(loops.Subject(x, np.asarray(y), te, tr, float(cfg["lam"]),
                                  int(cfg["num_classes"])))
     jax.block_until_ready([s.x for s in out])
@@ -159,7 +160,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, devices=None,
     counter = _CompileCounter()
     engine = make_engine(cell, precision)
     subjects = make_subjects(cell, seed)
-    loop = loops.make_loop(cell.traffic, engine, subjects, seed)
+    loop = loops.make_loop(cell, engine, subjects, seed)
     try:
         loop.setup(seconds)
         setup_s = time.perf_counter() - t_start
@@ -193,9 +194,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, devices=None,
     gc.collect()
 
     rec.update(setup_s=setup_s, window_compiles=counter.count, shapes={
-        "n": int(cfg["data"]["n_trials"]), "p": int(subjects[0].x64.shape[1]),
+        "n": int(cfg["data"]["n_trials"]), "p": int(subjects[0].x64.shape[-1]),
         "k": int(cfg["folds"]), "m": int(cfg["data"]["n_trials"]) // int(cfg["folds"]),
-        "num_classes": int(cfg["num_classes"])})
+        "num_classes": int(cfg["num_classes"]), "grid": list(subjects[0].x64.shape[:-2])})
     breakdown = None
     if trace:
         red = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir))

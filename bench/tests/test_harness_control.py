@@ -10,13 +10,17 @@ outside, as at full size;
 
 The reference's arithmetic with every contraction at one bfloat16 pass,
 put in the program's place on the label vectors the window served, must
-fail them too.
+fail them too. Where the engine has no ``bf16_gram`` path (the
+feature-sharded Gram of the four-chip cell), ``bench/calibrate.py`` skips
+that control and says so.
 """
+
+import json
 
 import pytest
 
 import bench.run as brun
-from bench import check
+from bench import calibrate, cells, check
 from bench.reference import refit
 
 CELLS = ["eeg_binary_p3800.perm1k", "eeg_3class_p1900.group16", "eeg_binary_p3800.http_cv"]
@@ -56,3 +60,25 @@ def test_one_pass_refit_in_the_programs_place_fails(tiny_cell, name):
     numbers["failed"] = keep["rec"]["failed"]
     correct, rows = check.verdict(numbers, cell.limits)
     assert not correct, rows
+
+
+def test_calibrate_skips_the_bf16_gram_control_where_the_gram_is_distributed(monkeypatch, capsys):
+    four = "eeg_binary_p3800_v5e4.perm10k"
+    assert "distributed" in calibrate.bf16_gram_skip(cells.resolve(four))
+    assert all(calibrate.bf16_gram_skip(cells.resolve(name)) is None for name in CELLS)
+    runs = []
+
+    def one(cell, seed, seconds, kind, precision=None):
+        runs.append((kind, precision))
+        return {"products": [], "subjects": [], "rec": {"failed": 0}}
+
+    monkeypatch.setattr(calibrate.brun, "tpu_devices", lambda chips: None)
+    monkeypatch.setattr(calibrate.brun, "setup_compilation_cache", lambda: "")
+    monkeypatch.setattr(calibrate, "one", one)
+    monkeypatch.setattr(calibrate.check, "check_closed", lambda *a, **kw: {"flips_per_1k": 0.0})
+    assert calibrate.main(["--workload", four, "--control-seeds", "1-3"]) == 0
+    lines = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert [line for line in lines if "skipped" in line] == [
+        {"kind": "control_bf16_gram", "skipped": calibrate.bf16_gram_skip(cells.resolve(four))}]
+    assert runs == [("program", None)] * 3
+    assert [line["kind"] for line in lines].count("control_one_pass") == 3

@@ -63,3 +63,17 @@ def test_engine_idle_sums_gaps_under_program_spans(cell, metric, count_key, scal
     assert read(parent) is None
     assert read({count_key: 1000}) is None
     assert read({count_key: 0, "device_trace": {"idle_gaps": _GAPS}}) is None
+
+
+@pytest.mark.parametrize("cell,metric,count_key", [
+    (PERM, "engine_idle_ms_per_1k.perm", "draws"),
+    (GROUP, "engine_idle_ms.group", "visits"),
+])
+def test_engine_idle_is_zero_when_spans_opened_and_no_gap_fell_in_one(cell, metric, count_key):
+    read = reader(cell, metric)
+    unnamed = {"idle_gaps": [g for g in _GAPS if "repro." not in g[0]]}
+    spans = _stages(null_chunk=(3, 0.006), gc=(0, 0.0))
+    assert read({count_key: 1000, "device_trace": unnamed, "stages": spans}) == 0.0
+    none_opened = _stages(null_chunk=(0, 0.0), gc=(0, 0.0))
+    assert read({count_key: 1000, "device_trace": unnamed, "stages": none_opened}) is None
+    assert read({count_key: 1000, "stages": spans}) is None  # an untraced run
